@@ -9,14 +9,13 @@ the paper's headline wire saving.
 
     PYTHONPATH=src python examples/transport_link.py
 """
-import threading
-
 import numpy as np
 
 from repro.core.symed import SymEDConfig
 from repro.data.synthetic import make_fleet
 from repro.launch.stream import StreamServer
-from repro.launch.transport import SenderClient, TransportServer, session_seed
+from repro.launch.transport import (
+    SenderClient, ServeThread, TransportServer, session_seed)
 
 N_STREAMS, LENGTH, WINDOW = 3, 256, 32
 
@@ -43,16 +42,14 @@ def main():
     server = StreamServer(cfg, max_sessions=8, window_cap=WINDOW,
                           digitize_every_k=1, autoscale=True, min_slots=1)
     transport = TransportServer(server, port=0)
-    thread = threading.Thread(
-        target=transport.serve,
-        kwargs={"expect_sessions": 2 * N_STREAMS}, daemon=True)
-    thread.start()
+    serving = ServeThread(transport, expect_sessions=2 * N_STREAMS)
     print(f"edge receiver listening on 127.0.0.1:{transport.port}")
 
     data = np.asarray(make_fleet(N_STREAMS, LENGTH, seed=4))
-    for mode in ("pieces", "raw"):
-        run_sender(transport.port, cfg, mode, data)
-    thread.join(timeout=60)
+    with serving.root_cause():
+        for mode in ("pieces", "raw"):
+            run_sender(transport.port, cfg, mode, data)
+    serving.join(timeout=60)
 
     rep = server.report(1.0)
     print(f"edge totals: {int(rep['points_in'])} points in, "

@@ -2,10 +2,9 @@
 
 ROADMAP standing policy: every API surface that was renamed across JAX
 releases (Pallas TPU memory spaces, compiler params, ``dimension_semantics``,
-``make_mesh`` axis types, ``shard_map``) is used through the feature-detected
-shims in ``repro/utils/jax_compat.py`` -- never directly.  A direct use works
-today and breaks on the next rename, silently for anyone not running the
-jax-canary job.
+``make_mesh`` axis types, ``shard_map``) is used through the names in
+``repro/utils/jax_compat.py`` -- never directly.  A direct use works today
+and breaks in every file at the next rename, instead of in one module.
 
 The banned-name table is **read out of jax_compat's module docstring** (the
 RST table that already documents each shim row): every ``pltpu.X`` /
@@ -33,15 +32,15 @@ PLTPU_MODULE = "jax.experimental.pallas.tpu"
 # compat module itself is outside the sweep (unit-test fixtures).  Keep in
 # sync with the docstring; the repo sweep always prefers the live docstring.
 FALLBACK_TOKENS = (
-    "pltpu.TPUMemorySpace", "pltpu.MemorySpace",
-    "pltpu.TPUCompilerParams", "pltpu.CompilerParams",
+    "pltpu.MemorySpace",
+    "pltpu.CompilerParams",
     "dimension_semantics=", "GridDimensionSemantics",
     "pltpu.VMEM",
     "axis_types=",
     "jax.make_mesh",
     "jax.experimental.shard_map", "jax.shard_map",
-    "check_rep=", "check_vma=",
-    "jax.profiler.TraceAnnotation", "jax.profiler.TraceContext",
+    "check_vma=",
+    "jax.profiler.TraceAnnotation",
 )
 
 _TOKEN_RE = re.compile(r"``([^`]+)``")

@@ -46,7 +46,10 @@ __all__ = [
     "scale_coords",
 ]
 
-_BIG = jnp.float32(1e30)
+_BIG = 1e30  # plain Python float: a jnp constant would touch the device at import
+# f32 matmuls at full precision: the TPU default rounds operands to bf16,
+# which moves labels off the reference's f32 semantics (a no-op on CPU)
+_HI = jax.lax.Precision.HIGHEST
 
 
 class DigitizerState(NamedTuple):
@@ -146,7 +149,7 @@ def _lloyd_half_step(
     onehot = jax.nn.one_hot(labels, k_max, dtype=jnp.float32)
     onehot = onehot * mask[:, None].astype(jnp.float32)
     counts = jnp.sum(onehot, axis=0)                      # (k_max,)
-    sums = onehot.T @ coords                              # (k_max, 2)
+    sums = jnp.matmul(onehot.T, coords, precision=_HI)    # (k_max, 2)
     return labels, sums, counts
 
 
@@ -209,7 +212,7 @@ def _pairwise_sq_dists(x: jax.Array, c: jax.Array) -> jax.Array:
     """||x_i - c_j||^2 via the MXU-friendly expansion (matches the kernel)."""
     x2 = jnp.sum(x * x, axis=1, keepdims=True)         # (n, 1)
     c2 = jnp.sum(c * c, axis=1)[None, :]               # (1, k)
-    cross = x @ c.T                                    # (n, k) -- MXU food
+    cross = jnp.matmul(x, c.T, precision=_HI)          # (n, k) -- MXU food
     return jnp.maximum(x2 - 2.0 * cross + c2, 0.0)
 
 
@@ -244,7 +247,7 @@ def _raw_centers(
     onehot = jax.nn.one_hot(labels, k_max, dtype=jnp.float32)
     onehot = onehot * mask[:, None].astype(jnp.float32)
     counts = jnp.sum(onehot, axis=0)
-    sums = onehot.T @ pieces
+    sums = jnp.matmul(onehot.T, pieces, precision=_HI)
     return sums / jnp.maximum(counts[:, None], 1.0), counts
 
 

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 __all__ = ["dtw_ref", "compression_rate_symed", "compression_rate_abba", "drr"]
 
-_INF = jnp.float32(1e30)
+_INF = 1e30  # plain Python float: a jnp constant would touch the device at import
 
 
 @functools.partial(jax.jit, static_argnames=("band",))
@@ -65,8 +65,8 @@ def dtw_ref(x: jax.Array, y: jax.Array, band: int | None = None) -> jax.Array:
         cur = jnp.where(valid, cur, _INF)
         return (prev, cur), None
 
-    prev2 = jnp.full(x.shape, _INF)
-    prev = jnp.full(x.shape, _INF)
+    prev2 = jnp.full(x.shape, _INF, jnp.float32)
+    prev = jnp.full(x.shape, _INF, jnp.float32)
     (prev, cur), _ = jax.lax.scan(
         diag_step, (prev2, prev), jnp.arange(n + m - 1)
     )

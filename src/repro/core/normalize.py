@@ -10,8 +10,9 @@ pair.  This module provides:
   * ``ewm_scan``       -- full-stream scan, batched over leading axes,
   * ``standardize``    -- z-score with a given (mean, var).
 
-``ewm_scan`` has a Pallas fast path (``repro.kernels.ops.ewma_scan``) used by
-the fleet runtime; this pure-jnp version is the reference oracle.
+The served compressor and the fleet runtime run ``ewm_step`` inside their
+scans.  ``ewm_scan`` is the oracle of the Pallas blocked scan
+(``repro.kernels.ops.ewma_scan``), which no served path calls.
 """
 from __future__ import annotations
 

@@ -8,12 +8,14 @@ evaluated by *anti-diagonal wavefront*: diagonal d holds cells (i, d-i), so the
 whole diagonal updates in one vectorized VPU step and only two previous
 diagonals are live.  TPU adaptation of the classic GPU wavefront:
 
-  * the i-axis is the 128-lane dimension; a full diagonal is a (bb, N) vreg row,
-  * ``y`` is stored *reversed* inside a 3N-wide VMEM buffer so the per-diagonal
-    gather ``y[d-i]`` becomes a dynamic lane *slice* (offset 2N-1-d) instead of
-    a gather,
+  * the i-axis is the 128-lane dimension; a full diagonal is a (bb, Np) row,
+  * ``y`` is stored *mirrored* in a 2Np-wide VMEM buffer (``ybuf[0] = y[0]``,
+    ``ybuf[W - j] = y[j]``), so the per-diagonal gather ``y[d-i]`` is one
+    lane rotation of that buffer by ``d`` (``pltpu.roll``), not a gather or
+    a dynamic slice,
   * the d-loop is a ``fori_loop`` with the two trailing diagonals as carries;
-    everything stays VMEM-resident, only the final (bb,) distances are written.
+    everything stays VMEM-resident, and the terminal cell is read with a
+    lane mask, so only the final (bb, 1) distances are written.
 
 Band (Sakoe-Chiba radius) masks cells with |i-j| > r at _BIG, bounding the
 useful work to O(N * r) while keeping the dense layout.
@@ -25,45 +27,42 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils.jax_compat import MemorySpace, tpu_compiler_params
+from repro.utils.jax_compat import tpu_compiler_params
 
 __all__ = ["dtw_pallas"]
 
 _BIG = 1e30  # plain Python float: jnp constants would be captured by the kernel
 
 
-def _kernel(meta_ref, x_ref, yr_ref, out_ref):
-    n_pad = x_ref.shape[1]
-    n = meta_ref[0]       # true length (both series)
-    r = meta_ref[1]       # band radius
-
+def _kernel(x_ref, yb_ref, out_ref, *, n, r):
     x = x_ref[...]                       # (bb, Np)
-    bb = x.shape[0]
+    yb = yb_ref[...]                     # (bb, 2Np) mirrored y
+    bb, n_pad = x.shape
     ii = jax.lax.broadcasted_iota(jnp.int32, (bb, n_pad), 1)
+
+    def shift(a):                        # a[:, i-1], _BIG into lane 0
+        return jnp.where(ii == 0, _BIG, pltpu.roll(a, 1, 1))
 
     def step(d, carry):
         prev2, prev = carry
         jj = d - ii
         valid = (ii < n) & (jj >= 0) & (jj < n) & (jnp.abs(ii - jj) <= r)
-
-        # y[d - i] == yrev[(N-1-d) + i] with yrev embedded at offset n_pad
-        off = n_pad + (n - 1) - d
-        yv = jax.lax.dynamic_slice(yr_ref[...], (0, off), (bb, n_pad))
+        # roll(yb, d)[i] = yb[(i - d) mod W] = y[d - i] wherever 0 <= d-i < n
+        yv = pltpu.roll(yb, d, 1)[:, :n_pad]
         cost = (x - yv) ** 2
-
-        shift = lambda a: jnp.concatenate(
-            [jnp.full((bb, 1), _BIG, jnp.float32), a[:, :-1]], axis=1
-        )
         best = jnp.minimum(jnp.minimum(shift(prev), prev), shift(prev2))
         best = jnp.where((ii == 0) & (jj == 0), 0.0, best)
         cur = jnp.where(valid, cost + best, _BIG)
         return prev, cur
 
-    init = (jnp.full((bb, n_pad), _BIG), jnp.full((bb, n_pad), _BIG))
-    _, last = jax.lax.fori_loop(0, 2 * n - 1, step, init)
+    # derived from an input rather than splatted: a constant carry takes a
+    # replicated vreg layout that the loop body's output cannot match
+    unreached = jnp.where(ii >= 0, _BIG, x)
+    _, last = jax.lax.fori_loop(0, 2 * n - 1, step, (unreached, unreached))
     # cell (n-1, n-1) lives at lane n-1 of the final diagonal
-    total = jax.lax.dynamic_slice(last, (0, n - 1), (bb, 1))[:, 0]
+    total = jnp.sum(jnp.where(ii == n - 1, last, 0.0), axis=1, keepdims=True)
     out_ref[...] = jnp.sqrt(total)
 
 
@@ -94,31 +93,28 @@ def dtw_pallas(
     b, n = x.shape
     r = max(int(band), abs(x.shape[1] - y.shape[1])) if band is not None else n
 
-    bb = min(block_b, _round_up(b, 8))
+    bb = _round_up(min(block_b, b), 8)
     bp = _round_up(b, bb)
     n_pad = _round_up(n, 128)
 
     x_p = jnp.pad(x, ((0, bp - b), (0, n_pad - n)))
-    # reversed y embedded in a 3*Np buffer at offset Np: yr[:, Np + j] = y[N-1-j]
-    y_rev = jnp.pad(y[:, ::-1], ((0, bp - b), (0, n_pad - n)))
-    y_buf = jnp.pad(y_rev, ((0, 0), (n_pad, n_pad)))
-
-    meta = jnp.asarray([n, r], jnp.int32)
+    # mirrored y in a 2*Np buffer: yb[:, 0] = y[0], yb[:, 2Np - j] = y[j]
+    y_p = jnp.pad(y, ((0, bp - b), (0, 2 * n_pad - n)))
+    y_buf = jnp.roll(y_p[:, ::-1], 1, axis=1)
 
     out = pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, n=n, r=r),
         grid=(bp // bb,),
         in_specs=[
-            pl.BlockSpec(memory_space=MemorySpace.SMEM),
             pl.BlockSpec((bb, n_pad), lambda i: (i, 0)),
-            pl.BlockSpec((bb, 3 * n_pad), lambda i: (i, 0)),
+            pl.BlockSpec((bb, 2 * n_pad), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((bb,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((bp,), jnp.float32),
+        out_specs=pl.BlockSpec((bb, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bp, 1), jnp.float32),
         compiler_params=tpu_compiler_params("parallel"),
         interpret=interpret,
-    )(meta, x_p, y_buf)
-    return out[:b]
+    )(x_p, y_buf)
+    return out[:b, 0]
 
 
 def _round_up(v: int, m: int) -> int:

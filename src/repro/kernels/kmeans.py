@@ -4,21 +4,32 @@ One Lloyd half-step for a *batch of independent clustering problems* (SymED
 receivers each own one): pairwise squared distances via the MXU-friendly
 expansion ``|x|^2 - 2 x.c^T + |c|^2``, masked argmin, and the per-cluster
 (sum, count) statistics needed for the center update -- all fused so the
-(N, K) distance matrix never leaves VMEM.
+(K, N) distance matrix never leaves VMEM.
 
-Layout: grid = (streams, N tiles).  Centers for the current stream stay
-resident; partial sums/counts accumulate directly in the output block (its
-index map is constant over the N-tile axis, so Pallas keeps it in VMEM and
-writes back once).  Feature dim D is padded to the 128-lane tile by the
-wrapper; SymED's piece space is D=2 but the kernel is written for general D
-(the benchmark sweeps D to show MXU utilization).
+Layout: grid = (streams, N tiles), every operand feature-major so pieces and
+centers sit on the 128-lane axis and the feature dim D on sublanes:
+
+  * ``xt``   (S, Dp, Np): rows ``< D`` the points, row ``D`` the 0/1 mask,
+  * ``ct``   (S, Dp, Kp): rows ``< D`` the centers, row ``D`` the 0/1 activity,
+  * labels   (S, 1, Np) i32 and stats (S, Dp, Kp) f32 out.
+
+``Dp = round_up(D + 1, 8)``, so SymED's D=2 piece space pads to one 8-row
+sublane tile rather than a 128-lane one.  Because the mask rides in row
+``D`` of ``xt``, the one stats matmul ``xt . onehot^T`` yields the per-cluster
+sums in rows ``< D`` and the counts in row ``D``.  The distance matrix is
+(Kp, bn), so the argmin reduces over sublanes and the labels come out
+lane-major, as the (1, bn) rows the output block wants.  The stats block's
+index map is constant over the N-tile axis, so it stays in VMEM and
+accumulates across tiles.  Both matmuls run at ``Precision.HIGHEST``: the
+default TPU matmul rounds f32 operands to bf16, which moves labels.
 
 This is the half-step the resident service's fused table digitize runs
 once per Lloyd iteration across the whole slot table
 (``core.digitize.masked_kmeans_table`` with ``use_kernel=True``, dispatched
 through ``kernels.ops.kmeans_assign``).  Contract note: the kernel zeroes
 the labels of masked-out pieces while the jnp reference path leaves the
-argmin there, so the kernel path is allclose-but-not-bitwise -- which is
+argmin there, and its sums differ from the reference's in float
+association, so the kernel path is allclose-but-not-bitwise -- which is
 why ``StreamServer`` defaults ``use_kernel`` to off on CPU, where the
 bitwise delta-equivalence battery runs.
 """
@@ -35,43 +46,44 @@ from repro.utils.jax_compat import tpu_compiler_params
 __all__ = ["kmeans_assign_pallas"]
 
 _BIG = 1e30  # plain Python float: jnp constants would be captured by the kernel
+_HI = jax.lax.Precision.HIGHEST
 
 
-def _kernel(x_ref, m_ref, c_ref, act_ref, lab_ref, sums_ref, cnt_ref):
+def _kernel(xt_ref, ct_ref, lab_ref, stats_ref, *, d):
     jt = pl.program_id(1)
-    x = x_ref[0]          # (bn, D)
-    m = m_ref[0]          # (bn,)   1.0 valid / 0.0 padded piece
-    c = c_ref[0]          # (K, D)
-    act = act_ref[0]      # (K,)    1.0 active center / 0.0 inactive
+    xt = xt_ref[0]                       # (Dp, bn)
+    ct = ct_ref[0]                       # (Dp, Kp)
+    kp, bn = ct.shape[1], xt.shape[1]
+    feat = jax.lax.broadcasted_iota(jnp.int32, (xt.shape[0], 1), 0) < d
+    x = jnp.where(feat, xt, 0.0)
+    c = jnp.where(feat, ct, 0.0)
+    valid = xt[d:d + 1, :] > 0.0                                   # (1, bn)
+    active = jnp.transpose(ct[d:d + 1, :]) > 0.0                   # (Kp, 1)
 
-    x2 = jnp.sum(x * x, axis=1, keepdims=True)                     # (bn, 1)
-    c2 = jnp.sum(c * c, axis=1)[None, :]                           # (1, K)
+    x2 = jnp.sum(x * x, axis=0, keepdims=True)                     # (1, bn)
+    c2 = jnp.transpose(jnp.sum(c * c, axis=0, keepdims=True))      # (Kp, 1)
     cross = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                                              # (bn, K) MXU
-    d = jnp.maximum(x2 - 2.0 * cross + c2, 0.0)
-    d = jnp.where(act[None, :] > 0.0, d, _BIG)
+        c, x, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_HI)         # (Kp, bn)
+    dist = jnp.maximum(x2 - 2.0 * cross + c2, 0.0)
+    dist = jnp.where(active, dist, _BIG)
 
-    labels = jnp.argmin(d, axis=1).astype(jnp.int32)               # (bn,)
-    lab_ref[0] = jnp.where(m > 0.0, labels, 0)
+    # argmin over the K sublanes, first index on ties (jnp.argmin's rule)
+    kid = jax.lax.broadcasted_iota(jnp.int32, (kp, bn), 0)
+    dmin = jnp.min(dist, axis=0, keepdims=True)
+    labels = jnp.min(jnp.where(dist == dmin, kid, kp), axis=0, keepdims=True)
+    lab_ref[0] = jnp.where(valid, labels, 0)                       # (1, bn)
 
-    k = c.shape[0]
-    onehot = (
-        labels[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-    ).astype(jnp.float32) * m[:, None]                             # (bn, K)
-
-    p_sums = jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )                                                              # (K, D) MXU
-    p_cnt = jnp.sum(onehot, axis=0)                                # (K,)
+    onehot = jnp.where((kid == labels) & valid, 1.0, 0.0)          # (Kp, bn)
+    stats = jax.lax.dot_general(
+        xt, onehot, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_HI)         # (Dp, Kp)
 
     @pl.when(jt == 0)
     def _():
-        sums_ref[0] = jnp.zeros_like(sums_ref[0])
-        cnt_ref[0] = jnp.zeros_like(cnt_ref[0])
+        stats_ref[0] = jnp.zeros_like(stats_ref[0])
 
-    sums_ref[0] += p_sums
-    cnt_ref[0] += p_cnt
+    stats_ref[0] += stats
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -98,41 +110,41 @@ def kmeans_assign_pallas(
     s, n, d = x.shape
     k = centers.shape[1]
 
-    dp = _round_up(d, 128)
+    dp = _round_up(d + 1, 8)
     kp = _round_up(k, 128)
-    bn = min(block_n, _round_up(n, 8))
+    bn = min(_round_up(block_n, 128), _round_up(n, 128))
     np_ = _round_up(n, bn)
 
-    x_p = jnp.pad(x, ((0, 0), (0, np_ - n), (0, dp - d)))
-    m_p = jnp.pad(mask.astype(jnp.float32), ((0, 0), (0, np_ - n)))
-    c_p = jnp.pad(jnp.asarray(centers, jnp.float32), ((0, 0), (0, kp - k), (0, dp - d)))
-    a_p = jnp.pad(center_active.astype(jnp.float32), ((0, 0), (0, kp - k)))
+    def feature_major(v, flag, width):
+        rows = jnp.concatenate(
+            [jnp.swapaxes(jnp.asarray(v, jnp.float32), 1, 2),
+             (flag > 0).astype(jnp.float32)[:, None, :]], axis=1)
+        return jnp.pad(rows, ((0, 0), (0, dp - d - 1), (0, width - rows.shape[2])))
 
-    grid = (s, np_ // bn)
-    labels, sums, counts = pl.pallas_call(
-        _kernel,
-        grid=grid,
+    xt = feature_major(x, mask, np_)
+    ct = feature_major(centers, center_active, kp)
+
+    labels, stats = pl.pallas_call(
+        functools.partial(_kernel, d=d),
+        grid=(s, np_ // bn),
         in_specs=[
-            pl.BlockSpec((1, bn, dp), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1, kp, dp), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, kp), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, dp, bn), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, dp, kp), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1, kp, dp), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, kp), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, bn), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, dp, kp), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((s, np_), jnp.int32),
-            jax.ShapeDtypeStruct((s, kp, dp), jnp.float32),
-            jax.ShapeDtypeStruct((s, kp), jnp.float32),
+            jax.ShapeDtypeStruct((s, 1, np_), jnp.int32),
+            jax.ShapeDtypeStruct((s, dp, kp), jnp.float32),
         ],
         # streams parallel, N tiles sequential (stats accumulate in-place)
         compiler_params=tpu_compiler_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(x_p, m_p, c_p, a_p)
-    return labels[:, :n], sums[:, :k, :d], counts[:, :k]
+    )(xt, ct)
+    sums = jnp.swapaxes(stats[:, :d, :k], 1, 2)
+    return labels[:, 0, :n], sums, stats[:, d, :k]
 
 
 def _round_up(v: int, m: int) -> int:

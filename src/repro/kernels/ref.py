@@ -13,7 +13,7 @@ from repro.core.normalize import ewm_scan as _ewm_core
 
 __all__ = ["ewma_scan_ref", "kmeans_assign_ref", "dtw_batch_ref"]
 
-_BIG = jnp.float32(1e30)
+_BIG = 1e30  # plain Python float: a jnp constant would touch the device at import
 
 
 def ewma_scan_ref(ts: jax.Array, alpha) -> tuple[jax.Array, jax.Array]:
@@ -34,7 +34,8 @@ def kmeans_assign_ref(
 
     k = centers.shape[1]
     onehot = jax.nn.one_hot(labels, k, dtype=jnp.float32) * mask[..., None]
-    sums = jnp.einsum("snk,snd->skd", onehot, x)
+    sums = jnp.einsum("snk,snd->skd", onehot, x,
+                      precision=jax.lax.Precision.HIGHEST)
     counts = jnp.sum(onehot, axis=1)
     return labels, sums, counts
 

@@ -35,6 +35,11 @@ def prescan_host_devices(argv=None, default: str = "1") -> None:
     the ``__main__`` blocks call this on ``sys.argv`` before importing
     anything that pulls in jax.  A malformed value is left for argparse to
     reject with a proper message.
+
+    The flag only forces devices on the *CPU* platform.  On an accelerator
+    the device count is the hardware's; the CLIs then build their mesh from
+    exactly ``--devices`` of those devices (``launch.fleet.fleet_data_mesh``),
+    and asking for more than exist is an error.
     """
     argv = sys.argv if argv is None else argv
     n = default
@@ -55,8 +60,9 @@ def prescan_host_devices(argv=None, default: str = "1") -> None:
 
 
 def add_devices_arg(ap: argparse.ArgumentParser, *, default: int = 1,
-                    help: str = "forced host device count; >1 shards "
-                                "over a data mesh") -> None:
+                    help: str = "devices in the data mesh (>1 shards over "
+                                "it); on the CPU platform, also the forced "
+                                "host device count") -> None:
     ap.add_argument("--devices", type=int, default=default, help=help)
 
 

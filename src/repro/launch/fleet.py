@@ -80,8 +80,15 @@ AxisSpec = Union[str, Sequence[str]]
 
 
 def fleet_data_mesh(n_devices: Optional[int] = None):
-    """1-D ``(data,)`` mesh over the first ``n_devices`` (default: all)."""
-    n = n_devices or jax.device_count()
+    """1-D ``(data,)`` mesh over exactly the first ``n_devices`` (default:
+    all).  Asking for more devices than the platform has is an error: a
+    ``--devices 8`` run on a one-chip machine must not quietly shrink."""
+    have = jax.device_count()
+    n = n_devices or have
+    if n > have:
+        raise ValueError(
+            f"asked for a {n}-device data mesh, but the "
+            f"{jax.default_backend()} platform has {have} device(s)")
     return make_mesh((n,), ("data",), devices=jax.devices()[:n])
 
 
@@ -377,7 +384,8 @@ def main():
     ap.add_argument("--reconstruct", action="store_true",
                     help="also reconstruct + score DTW error (slower)")
     add_devices_arg(ap, default=8,
-                    help="forced host device count for the CPU dry-run")
+                    help="devices in the data mesh (on the CPU platform, "
+                         "also the forced host device count)")
     add_symed_args(ap)
     add_metrics_args(ap)
     args = ap.parse_args()
@@ -388,8 +396,10 @@ def main():
                  f"--pods {args.pods}")
 
     from repro.data.synthetic import make_fleet
+    from repro.utils.compile_cache import enable_compile_cache
 
-    n_dev = jax.device_count()
+    enable_compile_cache()
+    n_dev = args.devices
     mesh, mesh_axes, layout = resolve_fleet_mesh(args.pods, n_dev)
     streams = max(args.streams - args.streams % n_dev, n_dev)
     cfg = SymEDConfig(tol=args.tol, alpha=args.alpha, n_max=256, k_max=32,

@@ -72,7 +72,7 @@ from repro.core.symed import (
 )
 from repro.kernels import ops
 from repro.obs import Observability, as_obs
-from repro.utils.jax_compat import trace_annotation
+from repro.utils.jax_compat import shard_map, trace_annotation
 
 __all__ = ["StreamServer", "main"]
 
@@ -85,37 +85,57 @@ def _null_annotation(name: str):
     return _NULL_ANN_CTX
 
 
+def _per_shard(fn, mesh):
+    """Run a slot-table function on each ``data`` shard's own slots.
+
+    Slots are independent, so a sharded table steps shard-locally with no
+    communication.  This is also what lets the Pallas kernel run on a mesh:
+    XLA cannot partition a Mosaic kernel call on its own.
+    """
+    if mesh is None:
+        return fn
+    return shard_map(fn, mesh, in_specs=P("data"), out_specs=P("data"))
+
+
 @functools.partial(
-    jax.jit, static_argnames=("cfg", "digitize_every_k", "use_kernel"),
+    jax.jit, static_argnames=("cfg", "digitize_every_k", "use_kernel", "mesh"),
     donate_argnums=(0,),
 )
 def _table_step(table, windows, n_valid, *, cfg, digitize_every_k,  # symlint: entry(drive=stream, budget=0, shapes=table-step)
-                use_kernel=False):
+                use_kernel=False, mesh=None):
     """One batched service step: every slot ingests its padded window.
 
     The table-level receive fuses the digitize pass across slots (one
     cursor loop sized by the widest span of new pieces, Pallas Lloyd
     half-steps when ``use_kernel``); the sender half vmaps per slot.  All
     loop-varying quantities (windows, valid counts, the in-state cadence
-    clock) are runtime operands -- only capacity changes retrace.
+    clock) are runtime operands -- only capacity changes retrace.  With a
+    ``mesh``, every shard steps its own slots (``_per_shard``).
     """
-    return symed_receive_masked_chunk_table(
-        windows, n_valid, cfg, table,
-        digitize_every_k=digitize_every_k, use_kernel=use_kernel,
-    )
+    def step(table, windows, n_valid):
+        return symed_receive_masked_chunk_table(
+            windows, n_valid, cfg, table,
+            digitize_every_k=digitize_every_k, use_kernel=use_kernel,
+        )
+
+    return _per_shard(step, mesh)(table, windows, n_valid)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cfg", "digitize_every_k", "use_kernel"),
+    jax.jit, static_argnames=("cfg", "digitize_every_k", "use_kernel", "mesh"),
     donate_argnums=(0,),
 )
 def _table_step_pieces(table, endpoints, steps, n_valid, hello, t_seen, *,  # symlint: entry(drive=stream, budget=0, shapes=table-step-pieces)
-                       cfg, digitize_every_k, use_kernel=False):
+                       cfg, digitize_every_k, use_kernel=False, mesh=None):
     """Compressed-in service step: every slot scatters its padded pieces."""
-    return symed_receive_masked_pieces_table(
-        endpoints, steps, n_valid, hello, t_seen, cfg, table,
-        digitize_every_k=digitize_every_k, use_kernel=use_kernel,
-    )
+    def step(table, endpoints, steps, n_valid, hello, t_seen):
+        return symed_receive_masked_pieces_table(
+            endpoints, steps, n_valid, hello, t_seen, cfg, table,
+            digitize_every_k=digitize_every_k, use_kernel=use_kernel,
+        )
+
+    return _per_shard(step, mesh)(table, endpoints, steps, n_valid, hello,
+                                  t_seen)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -417,11 +437,11 @@ class StreamServer:
             blanks, _ = _table_step(
                 blanks, win_f, cnt,
                 cfg=self.cfg, digitize_every_k=self.digitize_every_k,
-                use_kernel=self.use_kernel)
+                use_kernel=self.use_kernel, mesh=self._mesh)
             _table_step_pieces(
                 blanks, win_f, win_i, cnt, scal_f, scal_i,
                 cfg=self.cfg, digitize_every_k=self.digitize_every_k,
-                use_kernel=self.use_kernel)
+                use_kernel=self.use_kernel, mesh=self._mesh)
 
     def _blanks(self, n: int):
         """``n`` fresh blank slots (keys are placeholders; ``open`` reseeds)."""
@@ -555,7 +575,7 @@ class StreamServer:
                     self._table, info = _table_step(
                         self._table, windows, counts,
                         cfg=self.cfg, digitize_every_k=self.digitize_every_k,
-                        use_kernel=self.use_kernel)
+                        use_kernel=self.use_kernel, mesh=self._mesh)
                 if obs_on:
                     tracer.add("stream.dispatch", t_disp)
                     self._note_compiles()
@@ -683,7 +703,7 @@ class StreamServer:
                     self._table, info = _table_step_pieces(
                         self._table, *args,
                         cfg=self.cfg, digitize_every_k=self.digitize_every_k,
-                        use_kernel=self.use_kernel)
+                        use_kernel=self.use_kernel, mesh=self._mesh)
                 if obs_on:
                     tracer.add("stream.dispatch_pieces", t_disp)
                     self._note_compiles()
@@ -1006,20 +1026,23 @@ def main():
                     help="check delta concatenation against symed_encode")
     add_slot_table_args(ap, max_slots=4)
     add_devices_arg(
-        ap, help="forced host device count; >1 shards the slot table")
+        ap, help="devices in the data mesh; >1 shards the slot table (on "
+                 "the CPU platform, also the forced host device count)")
     add_symed_args(ap)
     add_metrics_args(ap)
     args = ap.parse_args()
     validate_cli_args(ap, args)
 
     from repro.launch.fleet import fleet_data_mesh
+    from repro.utils.compile_cache import enable_compile_cache
     from repro.workload.replay import replay_trace
 
+    enable_compile_cache()
     trace = _build_workload(args)
     window_cap = trace.window  # a recorded trace carries its own shape
     cfg = SymEDConfig(tol=args.tol, alpha=args.alpha, n_max=256, k_max=32,
                       len_max=256)
-    mesh = fleet_data_mesh() if args.devices > 1 else None
+    mesh = fleet_data_mesh(args.devices) if args.devices > 1 else None
     obs = Observability(trace_capacity=65536)
     server = StreamServer(
         cfg, max_sessions=args.max_slots, window_cap=window_cap,

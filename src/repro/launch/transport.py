@@ -61,9 +61,11 @@ if __name__ == "__main__":  # pragma: no cover -- CLI path only
     prescan_host_devices()
 
 import argparse
+import contextlib
 import select
 import socket
 import struct
+import threading
 import time
 import zlib
 from typing import Dict, List, NamedTuple, Optional, Tuple
@@ -79,7 +81,8 @@ __all__ = [
     "OPEN", "DATA", "CLOSE", "DELTA", "CLOSED", "ERROR",
     "Frame", "FrameDecoder", "SenderClient", "TransportServer",
     "encode_open", "encode_data_raw", "encode_data_pieces", "encode_close",
-    "encode_delta", "encode_closed", "encode_error", "main",
+    "encode_delta", "encode_closed", "encode_error", "ServeThread",
+    "pin_host_cpu", "main",
 ]
 
 OPEN, DATA, CLOSE, DELTA, CLOSED, ERROR = 1, 2, 3, 4, 5, 6
@@ -729,6 +732,71 @@ class TransportServer:
         }
 
 
+class ServeThread:
+    """``TransportServer.serve`` on a daemon thread whose failure reaches
+    the caller.
+
+    A compile error or a crash in the serve loop would otherwise surface
+    only as a sender timeout, or not at all.  ``join`` re-raises the
+    loop's exception, and treats a loop still running after ``timeout`` as
+    an error too.  Sender code runs inside ``root_cause()``: when the loop
+    dies it closes every connection, and the sender's resulting
+    connection error is replaced by the loop's own.
+    """
+
+    def __init__(self, transport: TransportServer, **serve_kw):
+        self.error: Optional[BaseException] = None
+        self._thread = threading.Thread(
+            target=self._run, args=(transport,), kwargs=serve_kw, daemon=True)
+        self._thread.start()
+
+    def _run(self, transport, **serve_kw) -> None:
+        try:
+            transport.serve(**serve_kw)
+        except BaseException as e:  # handed to the joining thread
+            self.error = e
+
+    def _raise_failure(self) -> None:
+        if self.error is not None:
+            raise RuntimeError(
+                f"transport serve loop failed: {self.error!r}") from self.error
+
+    @contextlib.contextmanager
+    def root_cause(self):
+        """Blame an exception in the block on the loop, if the loop failed
+        (giving a dying loop a few seconds to finish)."""
+        try:
+            yield
+        except Exception:
+            self._thread.join(timeout=5.0)
+            self._raise_failure()
+            raise
+
+    def join(self, timeout: float) -> None:
+        self._thread.join(timeout=timeout)
+        self._raise_failure()
+        if self._thread.is_alive():
+            raise TimeoutError(
+                f"transport serve loop still running after {timeout}s")
+
+
+def pin_host_cpu() -> None:
+    """Keep this process's JAX on the host CPU, off any accelerator.
+
+    The sender is the IoT side of the link: it must never take the chip
+    from the receiver process next to it.  Call before the first JAX
+    computation (importing this package runs none); raises if an
+    accelerator backend was already initialised.
+    """
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"JAX already holds the {jax.default_backend()} backend; the "
+            "sender must pin the CPU before its first JAX computation")
+
+
 # ------------------------------------------------------------------- CLI
 
 
@@ -739,7 +807,7 @@ def _serve_main(args) -> int:
 
     cfg = SymEDConfig(tol=args.tol, alpha=args.alpha, n_max=256, k_max=32,
                       len_max=256)
-    mesh = fleet_data_mesh() if args.devices > 1 else None
+    mesh = fleet_data_mesh(args.devices) if args.devices > 1 else None
     server = StreamServer(
         cfg, max_sessions=args.max_slots, window_cap=args.window,
         digitize_every_k=args.digitize_every, evict_idle=args.evict,
@@ -791,7 +859,9 @@ def _serve_main(args) -> int:
     return 0
 
 
-def _send_main(args) -> int:
+def _send_main(args, *, own_process: bool = True) -> int:
+    if own_process:
+        pin_host_cpu()  # the IoT side never holds the receiver's chip
     import jax
     import jax.numpy as jnp
 
@@ -850,10 +920,6 @@ def _send_main(args) -> int:
 
 def _demo_main(args) -> int:
     """In-process loopback: server thread + one sender per mode."""
-    import threading
-
-    import jax
-
     from repro.core.symed import SymEDConfig
     from repro.launch.stream import StreamServer
 
@@ -865,18 +931,16 @@ def _demo_main(args) -> int:
         min_slots=args.min_slots, seed=args.seed)
     transport = TransportServer(server, port=0)
     n_sessions = 2 * args.streams
-    thread = threading.Thread(
-        target=transport.serve, kwargs={"expect_sessions": n_sessions},
-        daemon=True)
-    thread.start()
+    serving = ServeThread(transport, expect_sessions=n_sessions)
     print(f"loopback server on port {transport.port}")
-    for mode in ("pieces", "raw"):
-        send_args = argparse.Namespace(
-            **{**vars(args), "mode": mode, "port": transport.port,
-               "host": "127.0.0.1", "session_prefix": f"demo-{mode}",
-               "verify": True})
-        _send_main(send_args)
-    thread.join(timeout=60)
+    with serving.root_cause():
+        for mode in ("pieces", "raw"):
+            send_args = argparse.Namespace(
+                **{**vars(args), "mode": mode, "port": transport.port,
+                   "host": "127.0.0.1", "session_prefix": f"demo-{mode}",
+                   "verify": True})
+            _send_main(send_args, own_process=False)
+    serving.join(timeout=60)
     rep = server.report(1.0)
     summ = transport.summary()
     print(f"wire in  (pieces mode)  : {int(summ['payload_bytes_pieces'])} B "
@@ -919,12 +983,16 @@ def main():
                     help="server: exit after this many sessions closed")
     add_slot_table_args(ap, max_slots=8)
     add_devices_arg(
-        ap, help="server: forced host device count (>1 shards the "
-                 "slot table)")
+        ap, help="server: devices in the data mesh (>1 shards the slot "
+                 "table; on the CPU platform, also the forced host device "
+                 "count)")
     add_symed_args(ap)
     add_metrics_args(ap)
     args = ap.parse_args()
     validate_shared_args(ap, args)
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.serve:
         return _serve_main(args)
     if args.send:

@@ -1,16 +1,12 @@
-"""Version-compat shims for JAX APIs that were renamed across releases.
+"""The single import point for version-sensitive JAX/Pallas API names.
 
-The kernels and launchers in this repo target the *current* Pallas/sharding
-API surface (``pltpu.MemorySpace``, ``pltpu.CompilerParams``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.shard_map``); the pinned
-toolchain in this container ships jax 0.4.37, where those names are still
-``pltpu.TPUMemorySpace`` / ``pltpu.TPUCompilerParams``, ``dimension_semantics``
-takes the string literals ``'parallel'``/``'arbitrary'`` instead of the
-``GridDimensionSemantics`` enum, ``make_mesh`` has no ``axis_types`` kwarg,
-and ``shard_map`` lives in ``jax.experimental`` with a ``check_rep`` flag.
-
-Everything is resolved by feature detection (never version string parsing),
-so the same source runs on both sides of each rename:
+The repo targets one installation: jax/jaxlib 0.9.0 with libtpu (the
+version ``pyproject.toml`` and CI pin).  The names below are the ones that
+moved or changed shape across recent releases (Pallas TPU memory spaces
+and compiler params, ``dimension_semantics`` enums, ``make_mesh`` axis
+types, ``shard_map`` and its replication check, profiler annotations).
+Kernels and launchers import them from here, so the next rename is one
+edit in this module instead of a sweep.
 
 This table is also the single source of truth for the ``SL001`` lint
 (``python -m repro.analysis``): every ````-quoted name or ``kwarg=`` token
@@ -20,26 +16,21 @@ here (with its table row) is how the banned list grows.
 ======================  ==============================  ========================
 concept                 version-sensitive spelling      routed through
 ======================  ==============================  ========================
-TPU memory spaces       ``pltpu.TPUMemorySpace``        ``MemorySpace``
-                        ``pltpu.MemorySpace``           ``MemorySpace``
+TPU memory spaces       ``pltpu.MemorySpace``           ``MemorySpace``
 VMEM scratch shapes     ``pltpu.VMEM``                  ``VMEM``
-compiler params         ``pltpu.TPUCompilerParams``     ``CompilerParams``
-                        ``pltpu.CompilerParams``        ``CompilerParams``
+compiler params         ``pltpu.CompilerParams``        ``CompilerParams``
 dimension semantics     ``dimension_semantics=``        ``tpu_compiler_params``
                         ``GridDimensionSemantics``      ``dimension_semantics``
 mesh construction       ``jax.make_mesh``               ``make_mesh``
 mesh axis types         ``axis_types=``                 ``make_mesh``
 shard_map               ``jax.experimental.shard_map``  ``shard_map``
                         ``jax.shard_map``               ``shard_map``
-replication check       ``check_rep=``                  ``shard_map``
-                        ``check_vma=``                  ``shard_map``
+replication check       ``check_vma=``                  ``shard_map``
 profiler annotations    ``jax.profiler.TraceAnnotation``  ``trace_annotation``
-                        ``jax.profiler.TraceContext``   ``trace_annotation``
 ======================  ==============================  ========================
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Optional, Sequence
 
 import jax
@@ -53,87 +44,57 @@ __all__ = [
     "tpu_compiler_params",
     "make_mesh",
     "shard_map",
-    "HAS_AXIS_TYPES",
     "trace_annotation",
 ]
 
-# --- Pallas TPU memory spaces ------------------------------------------------
-# pltpu.TPUMemorySpace (enum: ANY/SMEM/VMEM/CMEM/SEMAPHORE) was renamed to
-# pltpu.MemorySpace; members are identical.
-MemorySpace = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
+# --- Pallas TPU memory spaces and compiler params ---------------------------
+MemorySpace = pltpu.MemorySpace
+VMEM = pltpu.VMEM  # scratch-shape constructor (== MemorySpace.VMEM)
+CompilerParams = pltpu.CompilerParams
 
-# pltpu.VMEM (the scratch-shape constructor) predates the enum rename and may
-# disappear in favor of the enum member; prefer the module constant while it
-# exists, fall back to the enum.
-VMEM = getattr(pltpu, "VMEM", None)
-if VMEM is None:  # pragma: no cover -- future-API path
-    VMEM = MemorySpace.VMEM
-
-# --- Pallas TPU compiler params ---------------------------------------------
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-# GridDimensionSemantics is an enum-like namespace on new JAX; old JAX wants
-# the literal strings 'parallel' / 'arbitrary' (it also exposes module-level
-# pltpu.PARALLEL / pltpu.ARBITRARY sentinels, but the dataclass is typed for
-# the strings, so strings are the safe denominator there).
-_GDS = getattr(pltpu, "GridDimensionSemantics", None)
+_SEMANTICS = {
+    "parallel": pltpu.GridDimensionSemantics.PARALLEL,
+    "arbitrary": pltpu.GridDimensionSemantics.ARBITRARY,
+}
 
 
 def dimension_semantics(*kinds: str) -> tuple:
-    """Map ``'parallel'``/``'arbitrary'`` strings onto the installed API.
+    """Map ``'parallel'``/``'arbitrary'`` strings onto the grid enum.
 
     Usage::
 
         compiler_params=tpu_compiler_params("parallel", "arbitrary")
     """
     for k in kinds:
-        if k not in ("parallel", "arbitrary"):
+        if k not in _SEMANTICS:
             raise ValueError(f"unknown dimension semantic {k!r}")
-    if _GDS is not None and hasattr(_GDS, "PARALLEL"):
-        table = {"parallel": _GDS.PARALLEL, "arbitrary": _GDS.ARBITRARY}
-        return tuple(table[k] for k in kinds)
-    return tuple(kinds)
+    return tuple(_SEMANTICS[k] for k in kinds)
 
 
 def tpu_compiler_params(*kinds: str, **kwargs: Any):
-    """``CompilerParams`` with version-appropriate ``dimension_semantics``."""
+    """``CompilerParams`` with the grid's ``dimension_semantics``."""
     return CompilerParams(dimension_semantics=dimension_semantics(*kinds), **kwargs)
 
 
 # --- Mesh construction -------------------------------------------------------
-_MAKE_MESH_PARAMS = inspect.signature(jax.make_mesh).parameters
-HAS_AXIS_TYPES = "axis_types" in _MAKE_MESH_PARAMS and hasattr(
-    jax.sharding, "AxisType"
-)
-
-
 def make_mesh(
     axis_shapes: Sequence[int],
     axis_names: Sequence[str],
     *,
     devices: Optional[Sequence[Any]] = None,
 ):
-    """``jax.make_mesh`` that requests Auto axis types where supported.
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
 
-    On new JAX every axis is created as ``AxisType.Auto`` (the repo never uses
-    Explicit axes); on old JAX the kwarg simply does not exist and Auto is the
-    only behavior anyway.
+    The repo never uses Explicit axes; naming the type keeps sharding
+    propagation the same whatever the installed default is.
     """
-    kwargs: dict = {"devices": devices}
-    if HAS_AXIS_TYPES:
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)
 
 
 # --- shard_map ---------------------------------------------------------------
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # moved out of jax.experimental after 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SM_PARAMS = inspect.signature(_shard_map_impl).parameters
-
-
 def shard_map(
     f,
     mesh,
@@ -143,44 +104,21 @@ def shard_map(
     check_replication: bool = False,
     axis_names: Optional[frozenset] = None,
 ):
-    """Uniform ``shard_map`` across the ``check_rep`` -> ``check_vma`` rename.
+    """``jax.shard_map`` with the replication check off by default.
 
-    ``check_replication=False`` (the default) disables the out-spec
-    replication check under whichever flag name the installed JAX uses --
-    the fleet runtime emits psum-reduced telemetry whose replication the
-    old checker cannot always prove.
-
-    ``axis_names`` (new-API spelling): the subset of mesh axes the body is
-    manual over.  Old JAX expresses the same thing inverted, as
-    ``auto=<the other axes>``.
+    ``check_replication=False`` disables the out-spec replication check
+    (``check_vma``) -- the fleet runtime emits psum-reduced telemetry whose
+    replication the checker cannot always prove.  ``axis_names``: the
+    subset of mesh axes the body is manual over (default: all of them).
     """
-    kwargs: dict = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if "check_vma" in _SM_PARAMS:
-        kwargs["check_vma"] = check_replication
-    elif "check_rep" in _SM_PARAMS:
-        kwargs["check_rep"] = check_replication
+    kwargs: dict = {"mesh": mesh, "in_specs": in_specs,
+                    "out_specs": out_specs, "check_vma": check_replication}
     if axis_names is not None:
-        if "axis_names" in _SM_PARAMS:
-            kwargs["axis_names"] = frozenset(axis_names)
-        else:
-            kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map_impl(f, **kwargs)
+        kwargs["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(f, **kwargs)
 
 
 # --- profiler trace annotations ----------------------------------------------
-# jax.profiler.TraceAnnotation is the current spelling of the scoped
-# device-profile annotation; older releases only had TraceContext (and very
-# old ones neither).  The observability layer (repro.obs) routes through this
-# name so serving-loop spans can also land inside XLA device profiles.
-_trace_ann = getattr(jax.profiler, "TraceAnnotation", None)
-if _trace_ann is None:  # pragma: no cover -- old-API path
-    _trace_ann = getattr(jax.profiler, "TraceContext", None)
-
-if _trace_ann is not None:
-    trace_annotation = _trace_ann
-else:  # pragma: no cover -- profiler-less build
-    from contextlib import nullcontext as _nullcontext
-
-    def trace_annotation(name: str, **kwargs: Any):
-        """No-op stand-in when the installed jax has no profiler annotations."""
-        return _nullcontext()
+# The observability layer (repro.obs) routes through this name so
+# serving-loop spans can also land inside XLA device profiles.
+trace_annotation = jax.profiler.TraceAnnotation

@@ -233,10 +233,12 @@ def main(argv=None) -> int:
 
     from repro.core.symed import SymEDConfig
     from repro.launch.fleet import fleet_data_mesh
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = SymEDConfig(tol=args.tol, alpha=args.alpha, n_max=256, k_max=32,
                       len_max=256)
-    mesh = fleet_data_mesh() if args.devices > 1 else None
+    mesh = fleet_data_mesh(args.devices) if args.devices > 1 else None
 
     rows = []
     n_violations = 0

@@ -30,7 +30,6 @@ import dataclasses
 import hashlib
 import itertools
 import json
-import threading
 import time
 from typing import Dict, List, Optional
 
@@ -325,7 +324,7 @@ class _OverTransport:
     def __init__(self, trace: Trace, cfg, server, data: np.ndarray,
                  close_timeout: float):
         from repro.launch.transport import (
-            SenderClient, TransportServer, session_seed)
+            SenderClient, ServeThread, TransportServer, session_seed)
 
         self._session_seed = session_seed
         self.trace = trace
@@ -334,10 +333,8 @@ class _OverTransport:
         self.data = data
         self.close_timeout = close_timeout
         self.transport = TransportServer(server, host="127.0.0.1", port=0)
-        self.thread = threading.Thread(
-            target=self.transport.serve,
-            kwargs={"expect_sessions": len(trace.sessions)}, daemon=True)
-        self.thread.start()
+        self.serving = ServeThread(
+            self.transport, expect_sessions=len(trace.sessions))
         self.client = SenderClient(
             "127.0.0.1", self.transport.port, cfg, mode="raw",
             reply_timeout=close_timeout)
@@ -345,6 +342,10 @@ class _OverTransport:
         self.fed: Dict[str, List[np.ndarray]] = {}
 
     def drain(self, events) -> None:
+        with self.serving.root_cause():
+            self._drain(events)
+
+    def _drain(self, events) -> None:
         trace = self.trace
         for ev in events:
             if self.client.settled(ev.sid):
@@ -366,10 +367,11 @@ class _OverTransport:
         # every session settles via close() or a parked eviction CLOSED;
         # sids whose trace close was skipped (settled mid-run) still hold
         # their parked result
-        for sid in self.trace.sessions:
-            if sid not in self.results:
-                self.results[sid] = self.client.close(sid)
-        self.thread.join(timeout=self.close_timeout)
+        with self.serving.root_cause():
+            for sid in self.trace.sessions:
+                if sid not in self.results:
+                    self.results[sid] = self.client.close(sid)
+        self.serving.join(timeout=self.close_timeout)
         deltas = {}
         for sid in self.results:
             labels, endpoints = self.client.delta_concat(sid)

@@ -48,7 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.shard_map import shard_map
 
 def kernel(block):
-    return pltpu.TPUMemorySpace.ANY
+    return pltpu.MemorySpace.ANY
 
 def grid(params):
     return params(dimension_semantics=("parallel",))
@@ -71,7 +71,7 @@ class TestSL001:
         msgs = [f.message for f in result.findings]
         assert len(result.findings) == 4
         assert any("jax.experimental.shard_map" in m for m in msgs)
-        assert any("pltpu.TPUMemorySpace" in m for m in msgs)
+        assert any("pltpu.MemorySpace" in m for m in msgs)
         assert any("dimension_semantics" in m for m in msgs)
         assert any("jax.make_mesh" in m for m in msgs)
 
@@ -81,8 +81,8 @@ class TestSL001:
 
     def test_suppressed(self, tmp_path):
         src = SL001_POS.replace(
-            "return pltpu.TPUMemorySpace.ANY",
-            "return pltpu.TPUMemorySpace.ANY  # symlint: disable=SL001")
+            "return pltpu.MemorySpace.ANY",
+            "return pltpu.MemorySpace.ANY  # symlint: disable=SL001")
         result = run(tmp_path, {"mod.py": src}, ["SL001"])
         assert len(result.findings) == 3
         assert len(result.suppressed) == 1

@@ -59,6 +59,55 @@ class TestPrescan:
         assert "--xla_foo=1" in os.environ["XLA_FLAGS"]
 
 
+# ------------------------------------------------------- device meshes
+
+
+def test_mesh_larger_than_the_platform_is_an_error():
+    import jax
+
+    from repro.launch.fleet import fleet_data_mesh
+
+    have = jax.device_count()
+    assert fleet_data_mesh(have).devices.size == have
+    with pytest.raises(ValueError, match=f"{have + 1}-device data mesh"):
+        fleet_data_mesh(have + 1)
+
+
+# -------------------------------------------------------- compile cache
+
+
+class TestCompileCache:
+    @pytest.fixture
+    def updates(self, monkeypatch):
+        """Record config updates instead of turning the cache on here."""
+        import jax
+
+        seen = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda name, value: seen.append((name, value)))
+        return seen
+
+    def test_env_var_wins_and_code_sets_no_other_dir(self, monkeypatch,
+                                                     tmp_path, updates):
+        from repro.utils.compile_cache import ENV_VAR, enable_compile_cache
+
+        monkeypatch.setenv(ENV_VAR, str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert updates == []
+
+    def test_unset_uses_the_fixed_dir_in_the_checkout(self, monkeypatch,
+                                                      updates):
+        from repro.utils.compile_cache import ENV_VAR, enable_compile_cache
+
+        monkeypatch.delenv(ENV_VAR, raising=False)
+        path = REPO / ".jax_cache"
+        assert enable_compile_cache() == str(path)
+        assert enable_compile_cache() == str(path)  # no pid, time or temp
+        assert updates == [("jax_compilation_cache_dir", str(path))] * 2
+        ignored = (REPO / ".gitignore").read_text().split()
+        assert ".jax_cache/" in ignored
+
+
 # --------------------------------------------------------- validator unit
 
 

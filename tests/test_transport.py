@@ -14,7 +14,11 @@ Two layers:
   ``symed_encode`` -- including runs where the slot table autoscaled, and
   with sessions interleaving DATA over one connection.
 """
+import os
+import subprocess
+import sys
 import threading
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +37,7 @@ from repro.core.symed import SymEDConfig, symed_encode
 from repro.launch.stream import StreamServer
 from repro.launch.transport import (
     CLOSE, DATA, DELTA, ERROR, OPEN, FrameDecoder, SenderClient,
-    TransportServer, decode_close, decode_data_pieces, decode_data_raw,
+    ServeThread, TransportServer, decode_close, decode_data_pieces, decode_data_raw,
     encode_close, encode_data_pieces, encode_data_raw, encode_delta,
     encode_error, encode_open, session_seed,
 )
@@ -135,17 +139,14 @@ class _Loopback:
         kw.update(server_kw)
         self.stream = StreamServer(CFG, **kw)
         self.transport = TransportServer(self.stream, port=0)
-        self.thread = threading.Thread(
-            target=self.transport.serve,
-            kwargs={"expect_sessions": expect_sessions}, daemon=True)
-        self.thread.start()
+        self.serving = ServeThread(self.transport,
+                                   expect_sessions=expect_sessions)
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
-        self.thread.join(timeout=60)
-        assert not self.thread.is_alive(), "transport server failed to exit"
+        self.serving.join(timeout=60)
 
 
 def _feed_and_close(client, sids, streams, rng, lo=1, hi=49):
@@ -362,3 +363,61 @@ def test_loopback_autoscale_resizes_preserve_deltas(rng):
     assert lb.stream.totals["grows"] >= 2, lb.stream.totals
     assert lb.stream.totals["shrinks"] >= 1, lb.stream.totals
     assert lb.stream.capacity == 1
+
+
+# ------------------------------------------------------ serve-loop failures
+
+
+def test_serve_loop_failure_reaches_the_sender(rng, monkeypatch):
+    """A serve loop that dies mid-session is re-raised where the sender
+    runs, not left as a connection error or a timeout."""
+    def boom(self, *a, **kw):
+        raise ValueError("boom in the device step")
+
+    monkeypatch.setattr(StreamServer, "ingest_many", boom)
+    stream = StreamServer(CFG, max_sessions=2, window_cap=32)
+    transport = TransportServer(stream, port=0)
+    serving = ServeThread(transport, expect_sessions=1)
+    client = SenderClient("127.0.0.1", transport.port, CFG, mode="raw",
+                          reply_timeout=30)
+    with pytest.raises(RuntimeError, match="serve loop failed") as info:
+        with serving.root_cause():
+            client.open("s", session_seed("s", 0))
+            client.send("s", make_stream(rng, 32))
+            client.close("s")
+    assert isinstance(info.value.__cause__, ValueError)
+    with pytest.raises(RuntimeError, match="boom in the device step"):
+        serving.join(timeout=5)
+    client.shutdown()
+
+
+def test_serve_loop_still_running_after_join_is_an_error():
+    stop = threading.Event()
+    transport = TransportServer(
+        StreamServer(CFG, max_sessions=2, window_cap=32), port=0)
+    serving = ServeThread(transport, stop=stop)
+    with pytest.raises(TimeoutError, match="still running"):
+        serving.join(timeout=0.2)
+    stop.set()
+    serving.join(timeout=10)
+
+
+def test_sender_process_never_initialises_an_accelerator():
+    """The sender imports its modules without touching any JAX backend,
+    then pins the CPU: the receiver next to it keeps the chip."""
+    code = (
+        "from repro.launch import transport\n"
+        "import repro.core.symed, repro.data.synthetic, repro.core.compress\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge._backends, dict(xla_bridge._backends)\n"
+        "transport.pin_host_cpu()\n"
+        "import jax.numpy as jnp\n"
+        "jnp.ones(3).block_until_ready()\n"
+        "assert list(xla_bridge._backends) == ['cpu'], "
+        "list(xla_bridge._backends)\n"
+    )
+    repo = Path(__file__).resolve().parents[1]
+    env = {**os.environ, "PYTHONPATH": str(repo / "src")}
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr

@@ -293,6 +293,20 @@ class TestReplayDeterminism:
         assert runs[0].fingerprint() == runs[1].fingerprint()
         assert runs[0].counters == runs[1].counters
 
+    def test_transport_serve_failure_raises_in_caller(self, monkeypatch):
+        from repro.launch.stream import StreamServer
+        from repro.workload.replay import replay_trace
+
+        def boom(self, *a, **kw):
+            raise ValueError("boom in the device step")
+
+        monkeypatch.setattr(StreamServer, "ingest_many", boom)
+        tr = synthesize("flash_crowd", seed=scenario_seed("flash_crowd"),
+                        sessions=2, length=64, window=32)
+        with pytest.raises(RuntimeError, match="serve loop failed"):
+            replay_trace(tr, cfg=_small_cfg(), server_kw={"max_sessions": 2},
+                         transport=True, close_timeout=30)
+
     @pytest.mark.slow
     def test_transport_matches_inprocess(self):
         from repro.workload.replay import LOOSE_COUNTER_KEYS, replay_trace
