@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
+    "DigitizeWork",
     "DigitizerState",
     "digitizer_delta",
     "digitizer_init",
@@ -59,6 +60,37 @@ class DigitizerState(NamedTuple):
     centers: jax.Array  # (k_max, 2) raw space
     k: jax.Array        # () int32 -- number of active centers
     key: jax.Array      # PRNG key for the (rare) random re-init path
+
+
+class DigitizeWork(NamedTuple):
+    """Loop work of one digitize pass: ``()`` leaves for one slot, ``(S,)``
+    for a slot table.
+
+    Each ``digitizer_step`` runs one warm-start k-means and then one more
+    per k-growth round, ``lloyd_iters`` Lloyd iterations each, so a pass
+    runs ``lloyd_iters * (trips + rounds)`` Lloyd half-steps per lane.  A
+    slot table runs them over every lane at once for as long as its
+    widest span and slowest lane need: the useful share of that is
+    ``sum(trips + growing_rounds)`` over the table's
+    ``S * (max(trips) + rounds_run)``.
+
+    The per-lane counts are read off the span bounds and the digitizer's
+    k after the pass, and a table's loop carries one scalar: with three
+    more (S,) buffers in its carries, the TPU compiler moved small loop
+    buffers out of fast memory, and every growth round ran slower (a
+    table step about 1.5% slower on a TPU v5e).
+    """
+
+    trips: jax.Array           # pieces digitized: the lane's span length
+    growing_rounds: jax.Array  # k-growth rounds in which the lane grew k
+    rounds_run: jax.Array      # k-growth rounds its loop ran (table: the
+    #                            shard's rounds, on every lane alike)
+
+    @classmethod
+    def zeros(cls, shape=()) -> "DigitizeWork":
+        """No work: a pass that digitized nothing."""
+        z = jnp.zeros(shape, jnp.int32)
+        return cls(z, z, z)
 
 
 def digitizer_init(n_max: int, k_max: int, key: jax.Array) -> DigitizerState:
@@ -372,7 +404,7 @@ def digitize_span(  # symlint: entry(pair=span/slot, shapes=pair-span-slot)
     k_min: int,
     k_max_active: int,
     lloyd_iters: int = 10,
-) -> Tuple[DigitizerState, jax.Array]:
+) -> Tuple[DigitizerState, jax.Array, DigitizeWork]:
     """Ingest buffer slots ``lo <= idx < hi`` into a resumable digitizer.
 
     This is the online-receiver primitive: pieces live in the padded wire
@@ -383,8 +415,9 @@ def digitize_span(  # symlint: entry(pair=span/slot, shapes=pair-span-slot)
     ``lo=0`` instantiation, so resuming in any number of spans is
     bitwise-identical to one whole-buffer pass by construction.
 
-    Returns ``(state, symbols)`` -- ``symbols`` (n_max,) holds the symbol
-    emitted when each span slot arrived (0 outside the span).
+    Returns ``(state, symbols, work)`` -- ``symbols`` (n_max,) holds the
+    symbol emitted when each span slot arrived (0 outside the span);
+    ``work`` counts the loop's trips and k-growth rounds (``DigitizeWork``).
 
     The loop is a ``lax.while_loop`` over a cursor ``j in [lo, hi)``: the
     trip count is the number of pieces actually in the span, not ``n_max``.
@@ -417,11 +450,27 @@ def digitize_span(  # symlint: entry(pair=span/slot, shapes=pair-span-slot)
         )
         return st2, syms.at[jc].set(sym), j + 1
 
+    lo = jnp.asarray(lo, jnp.int32)
     final, symbols, _ = jax.lax.while_loop(
-        cond, body,
-        (state, jnp.zeros((n_max,), jnp.int32), jnp.asarray(lo, jnp.int32)),
+        cond, body, (state, jnp.zeros((n_max,), jnp.int32), lo),
     )
-    return final, symbols
+    trips = jnp.maximum(hi - lo, 0)
+    grew = _growing_rounds(state, final, trips, k_min)
+    return final, symbols, DigitizeWork(trips, grew, grew)
+
+
+def _growing_rounds(before, after, trips, k_min: int):
+    """k-growth rounds of ``trips`` digitizer steps, read off k.
+
+    A trivial-phase step sets ``k = n``, one more each step; a clustering
+    step starts from ``max(k, 1)`` and adds one center per growth round.
+    So what k gained beyond the trivial steps (and beyond the first
+    step's ``max(0, 1)`` when ``k_min`` is 0) it gained in growth rounds.
+    """
+    n = before.n
+    trivial = jnp.maximum(jnp.minimum(n + trips, k_min) - n, 0)
+    first = ((n == 0) & (trips > 0)).astype(jnp.int32) if k_min == 0 else 0
+    return after.k - before.k - trivial - first
 
 
 def _select_lanes(pred, new, old):
@@ -449,7 +498,7 @@ def digitizer_table_step(
     k_max_active: int,
     lloyd_iters: int = 10,
     use_kernel: bool = False,
-) -> Tuple[DigitizerState, jax.Array]:
+) -> Tuple[DigitizerState, jax.Array, jax.Array]:
     """Slot-table batch of ``digitizer_step``: every lane ingests one piece.
 
     Semantically ``jax.vmap(digitizer_step)`` with a per-lane ``live`` gate,
@@ -467,7 +516,8 @@ def digitizer_table_step(
       piece: (S, 2) one raw (len, inc) piece per lane.
       live:  (S,) bool -- lanes with ``live=False`` pass through unchanged.
 
-    Returns ``(state, symbols (S,))`` -- symbol 0 for dead lanes.
+    Returns ``(state, symbols (S,), rounds)`` -- symbol 0 for dead lanes;
+    ``rounds`` () is the number of k-growth rounds the table's loop ran.
     """
     n_streams, n_max = state.pieces.shape[0], state.pieces.shape[1]
     k_cap = state.centers.shape[1]
@@ -547,16 +597,21 @@ def digitizer_table_step(
         k_fin, c_fin, lab_fin, _, key = jax.lax.while_loop(
             cond, body, (k_o, c0, lab0, err0, state.key)
         )
+        # each lane grows in a prefix of the rounds, one center a round
+        rounds = jnp.max(k_fin - k_o)
         del c_fin  # raw-space centers are recomputed from the labeling
         centers_raw = jax.vmap(
             lambda p, m, l: _raw_centers(p, m, l, k_cap)[0]
         )(pieces, mask, lab_fin)
-        return DigitizerState(pieces, n, lab_fin, centers_raw, k_fin, key)
+        return DigitizerState(pieces, n, lab_fin, centers_raw, k_fin,
+                              key), rounds
 
-    stepped = _select_lanes(n <= k_min, trivial(), cluster())
+    trivial_state = trivial()
+    clustered, rounds = cluster()
+    stepped = _select_lanes(n <= k_min, trivial_state, clustered)
     symbol = jnp.take_along_axis(stepped.labels, (n - 1)[:, None], axis=1)[:, 0]
     new_state = _select_lanes(live, stepped, state)
-    return new_state, jnp.where(live, symbol, 0)
+    return new_state, jnp.where(live, symbol, 0), rounds
 
 
 def digitize_span_table(  # symlint: entry(pair=span/table, shapes=pair-span-table)
@@ -572,7 +627,7 @@ def digitize_span_table(  # symlint: entry(pair=span/table, shapes=pair-span-tab
     k_max_active: int,
     lloyd_iters: int = 10,
     use_kernel: bool = False,
-) -> Tuple[DigitizerState, jax.Array]:
+) -> Tuple[DigitizerState, jax.Array, DigitizeWork]:
     """Slot-table batch of ``digitize_span``: per-lane spans, shared loop.
 
     Every lane owns a cursor walking its ``[lo_s, hi_s)`` span; the loop
@@ -589,7 +644,11 @@ def digitize_span_table(  # symlint: entry(pair=span/table, shapes=pair-span-tab
       lengths/incs: (S, n_max) padded piece buffers.
       lo/hi: (S,) span bounds per lane (``lo == hi`` lanes are no-ops).
 
-    Returns ``(state, symbols (S, n_max))`` -- symbols 0 outside each span.
+    Returns ``(state, symbols (S, n_max), work)`` -- symbols 0 outside
+    each span; ``work`` (``DigitizeWork``, (S,) leaves) counts per lane the
+    trips it was live in and the growth rounds it grew in, and on every
+    lane the growth rounds the table's loop ran.  A lane's trips and
+    growing rounds equal the per-slot ``digitize_span``'s.
     """
     n_streams, n_max = lengths.shape
     pieces = jnp.stack(
@@ -597,15 +656,15 @@ def digitize_span_table(  # symlint: entry(pair=span/table, shapes=pair-span-tab
     )
 
     def cond(carry):
-        _, _, j = carry
+        _, _, j, _ = carry
         return jnp.any(j < hi)
 
     def body(carry):
-        st, syms, j = carry
+        st, syms, j, rounds_run = carry
         live = j < hi                                         # (S,)
         jc = jnp.minimum(j, n_max - 1)
         piece = jnp.take_along_axis(pieces, jc[:, None, None], axis=1)[:, 0]
-        st2, sym = digitizer_table_step(
+        st2, sym, rounds = digitizer_table_step(
             st, piece, live, tol=tol, scl=scl, k_min=k_min,
             k_max_active=k_max_active, lloyd_iters=lloyd_iters,
             use_kernel=use_kernel,
@@ -615,14 +674,17 @@ def digitize_span_table(  # symlint: entry(pair=span/table, shapes=pair-span-tab
         cur = jnp.take_along_axis(syms, jc[:, None], axis=1)[:, 0]
         syms2 = syms.at[jnp.arange(n_streams), jc].set(
             jnp.where(live, sym, cur))
-        return st2, syms2, jnp.where(live, j + 1, j)
+        return st2, syms2, jnp.where(live, j + 1, j), rounds_run + rounds
 
-    final, symbols, _ = jax.lax.while_loop(
+    lo = jnp.asarray(lo, jnp.int32)
+    final, symbols, _, rounds_run = jax.lax.while_loop(
         cond, body,
-        (state, jnp.zeros((n_streams, n_max), jnp.int32),
-         jnp.asarray(lo, jnp.int32)),
+        (state, jnp.zeros((n_streams, n_max), jnp.int32), lo, jnp.int32(0)),
     )
-    return final, symbols
+    trips = jnp.maximum(hi - lo, 0)
+    return final, symbols, DigitizeWork(
+        trips, _growing_rounds(state, final, trips, k_min),
+        jnp.broadcast_to(rounds_run, trips.shape))
 
 
 @functools.partial(
@@ -657,7 +719,7 @@ def digitize_pieces(  # symlint: entry(drive=digitize, budget=0, shapes=digitize
     n_max = lengths.shape[0]
     k_cap = int(k_cap)
     state = digitizer_init(n_max, k_cap, key)
-    final, symbols = digitize_span(
+    final, symbols, _ = digitize_span(
         state, lengths, incs, jnp.zeros((), jnp.int32), n_pieces,
         tol=tol, scl=scl, k_min=k_min, k_max_active=k_max_active,
         lloyd_iters=lloyd_iters,

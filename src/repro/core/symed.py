@@ -37,7 +37,8 @@ from repro.core.compress import (
     compressor_init, compressor_step,
 )
 from repro.core.digitize import (
-    DigitizerState, digitize_pieces, digitize_span, digitize_span_table,
+    DigitizerState, DigitizeWork, digitize_pieces, digitize_span,
+    digitize_span_table,
     digitizer_delta, digitizer_init,
 )
 from repro.core.metrics import compression_rate_symed, drr, dtw_ref
@@ -302,15 +303,27 @@ def _digitize_new_pieces(
     dig, symbols_online, endpoints, steps, n_pieces, t0, *, tol, scl, n_max,
     k_min, k_max, lloyd_iters
 ):
-    """Digitize buffer slots ``[dig.n, n_pieces)``; record first-time symbols."""
+    """Digitize buffer slots ``[dig.n, n_pieces)``; record first-time symbols.
+
+    Returns ``(dig, symbols_online, work)`` (``work``: the pass's
+    ``DigitizeWork``)."""
     lens, incs = pieces_from_wire(endpoints, steps, n_pieces, t0)
-    dig_new, span_syms = digitize_span(
+    dig_new, span_syms, work = digitize_span(
         dig, lens, incs, dig.n, n_pieces, tol=tol, scl=scl,
         k_min=k_min, k_max_active=k_max, lloyd_iters=lloyd_iters,
     )
     idx = jnp.arange(n_max)
     in_span = (idx >= dig.n) & (idx < n_pieces)
-    return dig_new, jnp.where(in_span, span_syms, symbols_online)
+    return dig_new, jnp.where(in_span, span_syms, symbols_online), work
+
+
+def _digitize_on_cadence(emitted, dig, symbols_online, digitize):
+    """``lax.cond(emitted, digitize, skip)`` for one slot: a skipped window
+    leaves the digitizer as it was and did no loop work."""
+    def skip(dig, symbols_online):
+        return dig, symbols_online, DigitizeWork.zeros()
+
+    return jax.lax.cond(emitted, digitize, skip, dig, symbols_online)
 
 
 def _digitize_new_pieces_table(
@@ -327,14 +340,14 @@ def _digitize_new_pieces_table(
     """
     lens, incs = jax.vmap(pieces_from_wire)(endpoints, steps, n_pieces, t0)
     hi = jnp.where(emitted, n_pieces, dig.n)
-    dig_new, span_syms = digitize_span_table(
+    dig_new, span_syms, work = digitize_span_table(
         dig, lens, incs, dig.n, hi, tol=tol, scl=scl,
         k_min=k_min, k_max_active=k_max, lloyd_iters=lloyd_iters,
         use_kernel=use_kernel,
     )
     idx = jnp.arange(n_max)[None, :]
     in_span = (idx >= dig.n[:, None]) & (idx < hi[:, None])
-    return dig_new, jnp.where(in_span, span_syms, symbols_online)
+    return dig_new, jnp.where(in_span, span_syms, symbols_online), work
 
 
 def _symbol_delta_info(n_dig_prev, dig, symbols_online, endpoints, emitted):
@@ -410,16 +423,13 @@ def _receive_chunk(  # symlint: entry(drive=chunked, budget=0, shapes=receive-ch
                 lloyd_iters=lloyd_iters,
             )
 
-        def skip(dig, symbols_online):
-            return dig, symbols_online
-
         emitted = chunks % digitize_every_k == 0
-        dig, symbols_online = jax.lax.cond(
-            emitted, digitize, skip, state.dig, state.symbols_online,
-        )
+        dig, symbols_online, work = _digitize_on_cadence(
+            emitted, state.dig, state.symbols_online, digitize)
     else:
         emitted = jnp.zeros((), bool)
-        dig, symbols_online = state.dig, state.symbols_online
+        dig, symbols_online, work = (state.dig, state.symbols_online,
+                                     DigitizeWork.zeros())
 
     new_state = ReceiverState(
         comp=comp, dig=dig, endpoints=endpoints, steps=steps,
@@ -433,6 +443,7 @@ def _receive_chunk(  # symlint: entry(drive=chunked, budget=0, shapes=receive-ch
         "symbol_delta": _symbol_delta_info(
             n_dig_prev, dig, symbols_online, endpoints, emitted
         ),
+        "work": work,
     }
     return new_state, info
 
@@ -467,7 +478,8 @@ def symed_receive_chunk(
     ``(labels, endpoints, n_new)`` symbols this call's digitize pass added
     (``emitted``/``frame_bytes`` describe the outbound frame; concatenating
     the deltas of every call plus the finish reproduces ``symbols_online``
-    exactly -- see ``repro.launch.stream``).
+    exactly -- see ``repro.launch.stream``).  ``info["work"]`` counts the
+    digitize loop's trips and k-growth rounds (``DigitizeWork``).
 
     Single-stream semantics ((C,) windows); ``jax.vmap`` over the leading
     axis for slabs (``repro.launch.fleet`` does exactly that).
@@ -568,16 +580,13 @@ def _masked_receive_chunk(
                 lloyd_iters=lloyd_iters,
             )
 
-        def skip_dig(dig, symbols_online):
-            return dig, symbols_online
-
         emitted = (n_valid > 0) & (chunks % digitize_every_k == 0)
-        dig, symbols_online = jax.lax.cond(
-            emitted, digitize, skip_dig, state.dig, state.symbols_online,
-        )
+        dig, symbols_online, work = _digitize_on_cadence(
+            emitted, state.dig, state.symbols_online, digitize)
     else:
         emitted = jnp.zeros((), bool)
-        dig, symbols_online = state.dig, state.symbols_online
+        dig, symbols_online, work = (state.dig, state.symbols_online,
+                                     DigitizeWork.zeros())
 
     new_state = ReceiverState(
         comp=comp, dig=dig, endpoints=endpoints, steps=steps,
@@ -592,6 +601,7 @@ def _masked_receive_chunk(
         "symbol_delta": _symbol_delta_info(
             n_dig_prev, dig, symbols_online, endpoints, emitted
         ),
+        "work": work,
     }
     return new_state, info
 
@@ -660,7 +670,9 @@ def symed_receive_masked_chunk_table(  # symlint: entry(pair=chunk/table, shapes
 
     Returns ``(table, info)`` shaped like a vmapped
     ``symed_receive_masked_chunk`` -- and, on the reference path, bitwise-
-    equal to it (property battery in ``tests/test_stream_service.py``).
+    equal to it (property battery in ``tests/test_stream_service.py``),
+    except ``info["work"].rounds_run``: every lane carries the k-growth
+    rounds the table's shared loop ran, where a slot counts its own.
     Callers jit this (``repro.launch.stream._table_step`` donates the table
     through it); it is not jitted here.
     """
@@ -675,7 +687,7 @@ def symed_receive_masked_chunk_table(  # symlint: entry(pair=chunk/table, shapes
     n_dig_prev = table.dig.n
     if digitize_every_k:
         emitted = (n_valid > 0) & (chunks % int(digitize_every_k) == 0)
-        dig, symbols_online = _digitize_new_pieces_table(
+        dig, symbols_online, work = _digitize_new_pieces_table(
             table.dig, table.symbols_online, endpoints, steps, n_pieces, t0,
             emitted, tol=cfg.tol, scl=cfg.scl, n_max=cfg.n_max,
             k_min=cfg.k_min, k_max=cfg.k_max, lloyd_iters=cfg.lloyd_iters,
@@ -683,7 +695,8 @@ def symed_receive_masked_chunk_table(  # symlint: entry(pair=chunk/table, shapes
         )
     else:
         emitted = jnp.zeros(n_valid.shape, bool)
-        dig, symbols_online = table.dig, table.symbols_online
+        dig, symbols_online, work = (table.dig, table.symbols_online,
+                                     DigitizeWork.zeros(n_valid.shape))
 
     new_table = ReceiverState(
         comp=comp, dig=dig, endpoints=endpoints, steps=steps,
@@ -698,6 +711,7 @@ def symed_receive_masked_chunk_table(  # symlint: entry(pair=chunk/table, shapes
         "symbol_delta": jax.vmap(_symbol_delta_info)(
             n_dig_prev, dig, symbols_online, endpoints, emitted
         ),
+        "work": work,
     }
     return new_table, info
 
@@ -739,7 +753,7 @@ def symed_receive_masked_pieces_table(  # symlint: entry(pair=pieces/table, shap
     n_dig_prev = table.dig.n
     if digitize_every_k:
         emitted = (n_valid > 0) & (chunks % int(digitize_every_k) == 0)
-        dig, symbols_online = _digitize_new_pieces_table(
+        dig, symbols_online, work = _digitize_new_pieces_table(
             table.dig, table.symbols_online, endpoints, steps, n_pieces, t0,
             emitted, tol=cfg.tol, scl=cfg.scl, n_max=cfg.n_max,
             k_min=cfg.k_min, k_max=cfg.k_max, lloyd_iters=cfg.lloyd_iters,
@@ -747,7 +761,8 @@ def symed_receive_masked_pieces_table(  # symlint: entry(pair=pieces/table, shap
         )
     else:
         emitted = jnp.zeros(n_valid.shape, bool)
-        dig, symbols_online = table.dig, table.symbols_online
+        dig, symbols_online, work = (table.dig, table.symbols_online,
+                                     DigitizeWork.zeros(n_valid.shape))
 
     new_table = ReceiverState(
         comp=table.comp, dig=dig, endpoints=endpoints, steps=steps,
@@ -762,6 +777,7 @@ def symed_receive_masked_pieces_table(  # symlint: entry(pair=pieces/table, shap
         "symbol_delta": jax.vmap(_symbol_delta_info)(
             n_dig_prev, dig, symbols_online, endpoints, emitted
         ),
+        "work": work,
     }
     return new_table, info
 
@@ -803,16 +819,13 @@ def _masked_receive_pieces(
                 lloyd_iters=lloyd_iters,
             )
 
-        def skip_dig(dig, symbols_online):
-            return dig, symbols_online
-
         emitted = (n_valid > 0) & (chunks % digitize_every_k == 0)
-        dig, symbols_online = jax.lax.cond(
-            emitted, digitize, skip_dig, state.dig, state.symbols_online,
-        )
+        dig, symbols_online, work = _digitize_on_cadence(
+            emitted, state.dig, state.symbols_online, digitize)
     else:
         emitted = jnp.zeros((), bool)
-        dig, symbols_online = state.dig, state.symbols_online
+        dig, symbols_online, work = (state.dig, state.symbols_online,
+                                     DigitizeWork.zeros())
 
     new_state = ReceiverState(
         comp=state.comp, dig=dig, endpoints=endpoints, steps=steps,
@@ -827,6 +840,7 @@ def _masked_receive_pieces(
         "symbol_delta": _symbol_delta_info(
             n_dig_prev, dig, symbols_online, endpoints, emitted
         ),
+        "work": work,
     }
     return new_state, info
 
@@ -910,7 +924,7 @@ def _receive_finish(  # symlint: entry(drive=chunked, budget=0, shapes=receive-f
         state.endpoints, state.steps, state.n_pieces, tail, state.t_seen
     )
     lens, incs = pieces_from_wire(endpoints, steps, n_pieces, state.t0)
-    dig, span_syms = digitize_span(
+    dig, span_syms, _ = digitize_span(
         state.dig, lens, incs, state.dig.n, n_pieces, tol=tol, scl=scl,
         k_min=k_min, k_max_active=k_max, lloyd_iters=lloyd_iters,
     )

@@ -184,6 +184,15 @@ def _finalize_deltas(deltas: Dict[str, dict]) -> Dict[str, dict]:
     return deltas
 
 
+def symbol_latency_histogram(metrics):
+    """The paper's per-symbol latency series (get-or-create, so a transport
+    in front of the server records into the same instrument)."""
+    return metrics.histogram(
+        "symed_symbol_latency_seconds",
+        "per-symbol latency: window arrival to delta-frame emit "
+        "(the paper's 42 ms metric)", unit="ns")
+
+
 @dataclasses.dataclass
 class _Session:
     """Host-side bookkeeping for one live slot (device state is the table)."""
@@ -382,13 +391,23 @@ class StreamServer:
         scrape-time callback series instead -- zero added hot-path work.
         """
         m = self.obs.metrics
-        self._h_symbol_lat = m.histogram(
-            "symed_symbol_latency_seconds",
-            "per-symbol latency: window arrival to delta-frame emit "
-            "(the paper's 42 ms metric)", unit="ns")
+        self._h_symbol_lat = symbol_latency_histogram(m)
         self._h_tick = m.histogram(
             "symed_ingest_tick_seconds",
             "per-round ingest latency: pack + dispatch + harvest", unit="ns")
+        # the digitize loop's work, read from each step's outputs at harvest
+        self._m_trips = m.counter(
+            "symed_digitize_trips_total",
+            "table-step digitize trips (each step: its widest span)")
+        self._m_rounds = m.counter(
+            "symed_kgrowth_rounds_total",
+            "table-step k-growth rounds (each step: its slowest lane's)")
+        runs_help = ("table-step Lloyd k-means runs times lanes: executed "
+                     "(every lane), useful (lanes digitizing or growing k)")
+        self._m_lane_runs = m.counter("symed_lloyd_lane_runs_total",
+                                      runs_help, labels={"kind": "executed"})
+        self._m_useful_runs = m.counter("symed_lloyd_lane_runs_total",
+                                        runs_help, labels={"kind": "useful"})
         if not self._obs_on:
             return
         t = self.totals
@@ -491,6 +510,7 @@ class StreamServer:
         """
         if stream_id in self._sessions:
             raise ValueError(f"session {stream_id!r} is already open")
+        t_open = time.perf_counter_ns() if self._obs_on else 0
         if not self._free and self.capacity < self.max_sessions:
             self._grow()
         if not self._free:
@@ -516,13 +536,16 @@ class StreamServer:
         )
         self.totals["opened"] += 1
         self.totals["bytes_in"] += 4.0  # the t0 "hello" payload
+        if self._obs_on:
+            self.obs.tracer.add("stream.open", t_open)
         return slot
 
     def ingest(self, stream_id: str, window) -> dict:
         """Feed one ragged arrival; returns its symbol-delta frame."""
         return self.ingest_many({stream_id: window})[stream_id]
 
-    def ingest_many(self, arrivals: Dict[str, object]) -> Dict[str, dict]:  # symlint: hot-path
+    def ingest_many(self, arrivals: Dict[str, object], *,  # symlint: hot-path
+                    record_latency: bool = True) -> Dict[str, dict]:
         """Feed concurrent arrivals through one batched step per round.
 
         ``arrivals`` maps open stream ids to 1-D float windows of any
@@ -535,7 +558,13 @@ class StreamServer:
         dispatched (async), round ``r+1`` is packed host-side, and only
         then is round ``r``'s output transferred back -- host staging and
         accounting overlap device work instead of serializing with it.
+
+        Every symbol returned counts in ``symed_symbol_latency_seconds``
+        from this call to its return; ``record_latency=False`` leaves that
+        to a caller that stamps the window's arrival and the frame's emit
+        itself (the transport: frame read to DELTA write).
         """
+        t_call = time.perf_counter_ns() if self._obs_on else 0
         wins = {}
         for sid, w in arrivals.items():
             if sid not in self._sessions:
@@ -592,22 +621,28 @@ class StreamServer:
             self._harvest_round(pend_active, pend_info, pend_clock, deltas,
                                 pend_t0)
         self._run_dtw_monitor()
+        if obs_on and record_latency:
+            self._observe_latency(t_call, deltas)
         return _finalize_deltas(deltas)
+
+    def _observe_latency(self, t0_ns: int, deltas: Dict[str, dict]) -> None:
+        n = sum(d["n_new"] for d in deltas.values())
+        if n:
+            self._h_symbol_lat.observe_n(time.perf_counter_ns() - t0_ns, n)
 
     def _harvest_round(self, active, info, clock, deltas, t0_ns=0) -> None:
         """Transfer one round's outputs and fold them into the books.
 
-        ``t0_ns`` is the round's host arrival stamp (pack start), so the
-        latency histograms measure the full arrival -> delta-frame-emit
-        path across the double buffer.
+        ``t0_ns`` is the round's pack start, so the tick histogram measures
+        pack -> dispatch -> harvest across the double buffer.
         """
         obs_on = self._obs_on
         t_h = time.perf_counter_ns() if obs_on else 0
         d = info["symbol_delta"]
         # one blocking transfer per round, not one per output leaf
-        labels, endpoints, n_new, emitted, t_seen = jax.device_get(  # sync: ok
+        labels, endpoints, n_new, emitted, t_seen, work = jax.device_get(  # sync: ok
             (d["labels"], d["endpoints"], d["n_new"], d["emitted"],
-             info["t_seen"]))
+             info["t_seen"], info["work"] if obs_on else None))
         lat = (time.perf_counter_ns() - t0_ns) if obs_on else 0
         for sid, part in active:
             sess = self._sessions[sid]
@@ -616,8 +651,6 @@ class StreamServer:
                 sess, deltas[sid], labels[sess.slot],
                 endpoints[sess.slot], n,
                 bool(emitted[sess.slot]))
-            if obs_on and n:
-                self._h_symbol_lat.observe_n(lat, n)
             sess.chunks += 1
             sess.t_seen = int(t_seen[sess.slot])
             sess.last_active = clock
@@ -631,9 +664,11 @@ class StreamServer:
         if obs_on:
             self._h_tick.observe(lat)
             self.obs.tracer.add("stream.harvest", t_h,
-                                {"sessions": len(active)})
+                                {"sessions": len(active),
+                                 **self._record_work(active, work)})
 
-    def ingest_pieces_many(self, arrivals: Dict[str, dict]) -> Dict[str, dict]:  # symlint: hot-path
+    def ingest_pieces_many(self, arrivals: Dict[str, dict], *,  # symlint: hot-path
+                           record_latency: bool = True) -> Dict[str, dict]:
         """Compressed-in counterpart of ``ingest_many``.
 
         Each arrival carries pieces the *sender's* compressor finished
@@ -647,6 +682,7 @@ class StreamServer:
         pieces-mode sessions may share one table (idle slots mask out of
         either batched step), but a single session must stay in one mode.
         """
+        t_call = time.perf_counter_ns() if self._obs_on else 0
         pends = {}
         for sid, a in arrivals.items():
             if sid not in self._sessions:
@@ -719,6 +755,8 @@ class StreamServer:
         if pend_active:
             self._harvest_pieces_round(pend_active, pend_info, pend_clock,
                                        deltas, pend_t0)
+        if obs_on and record_latency:
+            self._observe_latency(t_call, deltas)
         return _finalize_deltas(deltas)
 
     def _harvest_pieces_round(self, active, info, clock, deltas,
@@ -728,9 +766,9 @@ class StreamServer:
         t_h = time.perf_counter_ns() if obs_on else 0
         d = info["symbol_delta"]
         # one blocking transfer per round, not one per output leaf
-        labels, endpoints, n_new, emitted, t_seen = jax.device_get(  # sync: ok
+        labels, endpoints, n_new, emitted, t_seen, work = jax.device_get(  # sync: ok
             (d["labels"], d["endpoints"], d["n_new"], d["emitted"],
-             info["t_seen"]))
+             info["t_seen"], info["work"] if obs_on else None))
         lat = (time.perf_counter_ns() - t0_ns) if obs_on else 0
         for sid, n_in in active:
             sess = self._sessions[sid]
@@ -739,8 +777,6 @@ class StreamServer:
                 sess, deltas[sid], labels[sess.slot],
                 endpoints[sess.slot], n,
                 bool(emitted[sess.slot]))
-            if obs_on and n:
-                self._h_symbol_lat.observe_n(lat, n)
             if n_in:
                 sess.chunks += 1
             now_seen = int(t_seen[sess.slot])
@@ -750,7 +786,40 @@ class StreamServer:
         if obs_on:
             self._h_tick.observe(lat)
             self.obs.tracer.add("stream.harvest_pieces", t_h,
-                                {"sessions": len(active)})
+                                {"sessions": len(active),
+                                 **self._record_work(active, work)})
+
+    def _record_work(self, active, work) -> dict:
+        """Count one step's digitize work and return it as span args.
+
+        ``work`` is the step's ``DigitizeWork`` on the host.  Each shard
+        runs its own loop, ``lloyd_iters`` Lloyd calls over its lanes per
+        trip and per growth round; ``trips``/``rounds`` are the step's
+        largest (on one chip, its Lloyd calls are exactly ``lloyd_iters *
+        (trips + rounds)``), ``lane_runs`` every lane of those calls and
+        ``useful_runs`` the lanes digitizing a piece or growing k.
+        ``slowest`` is the session that grew k in the most rounds.
+        """
+        trips, growing, rounds_run = work
+        shards = self._mesh.devices.size if self._mesh is not None else 1
+        per_shard = trips.shape[0] // shards
+        widest = trips.reshape(shards, per_shard).max(axis=1)
+        rounds = rounds_run.reshape(shards, per_shard)[:, 0]
+        args = {
+            "trips": int(widest.max()), "rounds": int(rounds.max()),
+            "lane_runs": int(per_shard * (widest + rounds).sum()),
+            "useful_runs": int(trips.sum() + growing.sum()),
+            "slowest": "", "slowest_rounds": 0,
+        }
+        for sid, _ in active:
+            g = int(growing[self._sessions[sid].slot])
+            if g > args["slowest_rounds"]:
+                args["slowest"], args["slowest_rounds"] = sid, g
+        self._m_trips.inc(args["trips"])
+        self._m_rounds.inc(args["rounds"])
+        self._m_lane_runs.inc(args["lane_runs"])
+        self._m_useful_runs.inc(args["useful_runs"])
+        return args
 
     def close(self, stream_id: str) -> dict:
         """Flush the tail, emit the closing delta frame, free the slot.
@@ -762,6 +831,7 @@ class StreamServer:
         sess = self._sessions.pop(stream_id, None)
         if sess is None:
             raise KeyError(f"unknown session {stream_id!r}")
+        t_close = time.perf_counter_ns() if self._obs_on else 0
         delta = {"labels": np.zeros((0,), np.int32),
                  "endpoints": np.zeros((0,), np.float32),
                  "n_new": 0, "frames": 0, "bytes": 0.0}
@@ -786,6 +856,9 @@ class StreamServer:
         self._free.append(sess.slot)
         self.totals["closed"] += 1
         self._maybe_shrink()
+        if self._obs_on:
+            self.obs.tracer.add("stream.close", t_close,
+                                {"symbols": delta["n_new"]})
         return {
             "stream_id": stream_id,
             "out": out,

@@ -430,6 +430,8 @@ class TransportServer:
         self.frame_bytes = 0.0      # total socket bytes in (incl. framing)
         self.payload_bytes = {MODE_RAW: 0.0, MODE_PIECES: 0.0}
         self.raw_equiv_bytes = {MODE_RAW: 0.0, MODE_PIECES: 0.0}
+        self._wait_t0 = 0           # start of the open transport.wait stretch
+        self._wait_polls = 0        # select calls in it
         self._register_metrics()
 
     def _register_metrics(self) -> None:
@@ -440,11 +442,14 @@ class TransportServer:
         series (zero loop cost); per-frame/decode signals are live counters
         and histograms recorded in ``_tick``/``_process``.
         """
+        from repro.launch.stream import symbol_latency_histogram
         from repro.obs import disabled
 
         self._obs = getattr(self.server, "obs", None) or disabled()
         self._obs_on = self._obs.enabled
         m = self._obs.metrics
+        # measured here, frame read to DELTA write; the server leaves it to us
+        self._h_symbol_lat = symbol_latency_histogram(m)
         self._h_decode = m.histogram(
             "transport_decode_seconds",
             "per-recv frame decode latency", unit="ns")
@@ -481,9 +486,6 @@ class TransportServer:
         m.counter_fn("transport_payload_bytes_total", "payload bytes by mode",
                      lambda: float(self.payload_bytes[MODE_PIECES]),
                      labels={"mode": "pieces"})
-        m.counter_fn("transport_sessions_closed_total",
-                     "sessions closed over the wire",
-                     lambda: float(self.closed_sessions))
         m.gauge_fn("transport_open_connections", "live sender sockets",
                    lambda: float(len(self._conns)))
 
@@ -512,9 +514,21 @@ class TransportServer:
     # ------------------------------------------------------------ internals
 
     def _tick(self, poll: float) -> None:
+        obs_on = self._obs_on
+        t_sel = time.perf_counter_ns() if obs_on else 0
         rlist, _, _ = select.select(
             [self.listener, *self._conns], [], [], poll)
-        staged: List[Tuple[socket.socket, Frame]] = []
+        if obs_on:
+            # one transport.wait span per stretch of polls, closed by the
+            # select that returns work (not one span per empty poll)
+            if not self._wait_polls:
+                self._wait_t0 = t_sel
+            self._wait_polls += 1
+            if rlist:
+                self._obs.tracer.add("transport.wait", self._wait_t0,
+                                     {"polls": self._wait_polls})
+                self._wait_polls = 0
+        staged: List[Tuple[socket.socket, Frame, int]] = []
         for sock_ in rlist:
             if sock_ is self.listener:
                 conn, _ = self.listener.accept()
@@ -528,18 +542,15 @@ class TransportServer:
                 self._drop_conn(sock_)
                 continue
             self.frame_bytes += len(data)
-            t_dec = time.perf_counter_ns() if self._obs_on else 0
+            t_dec = time.perf_counter_ns() if obs_on else 0
             try:
                 frames = self._conns[sock_].feed(data)
             except ValueError as e:
                 self._m_proto_errors.inc()
-                try:
-                    sock_.sendall(encode_error("", f"protocol error: {e}"))
-                except OSError:
-                    pass
+                self._reply(sock_, encode_error, "", f"protocol error: {e}")
                 self._drop_conn(sock_)
                 continue
-            if self._obs_on:
+            if obs_on:
                 self._h_decode.observe(time.perf_counter_ns() - t_dec)
                 self._obs.tracer.add(
                     "transport.decode", t_dec,
@@ -548,7 +559,8 @@ class TransportServer:
                 for f in frames:
                     (frame_counters.get(f.type)
                      or self._m_frames_other).inc()
-            staged.extend((sock_, f) for f in frames)
+            # t_dec: when the frames were read (the symbol latency's start)
+            staged.extend((sock_, f, t_dec) for f in frames)
         if staged:
             self._process(staged)
 
@@ -563,31 +575,41 @@ class TransportServer:
                 self.server.close(sid)
                 self.closed_sessions += 1
 
-    def _reply(self, conn, data: bytes) -> None:
+    def _send(self, conn, data: bytes) -> None:
         try:
             conn.sendall(data)
             self._m_tx.inc(len(data))
         except OSError:
             self._drop_conn(conn)
 
+    def _reply(self, conn, encode, *args) -> None:
+        """Encode one frame and write it back (a ``transport.reply`` span)."""
+        t_rep = time.perf_counter_ns() if self._obs_on else 0
+        self._send(conn, encode(*args))
+        if self._obs_on:
+            self._obs.tracer.add("transport.reply", t_rep, {"frames": 1})
+
     def _process(self, staged) -> None:
         t_route = time.perf_counter_ns() if self._obs_on else 0
         raw_batch: Dict[str, list] = {}
         pieces_batch: Dict[str, dict] = {}
         closes: List[str] = []
-        for conn, frame in staged:
+        read_ns: Dict[str, int] = {}  # each session's first DATA frame read
+        for conn, frame, t_read in staged:
             try:
                 self._handle_frame(conn, frame, raw_batch, pieces_batch,
                                    closes)
+                if frame.type == DATA:
+                    read_ns.setdefault(frame.sid, t_read)
             except (struct.error, ValueError, IndexError) as e:
                 # a well-framed body with garbage inside must not take the
                 # serve loop (and every other tenant) down -- the offending
                 # connection is dropped, its sessions closed server-side
                 self._m_proto_errors.inc()
-                self._reply(conn, encode_error(
-                    frame.sid, f"malformed frame payload: {e}"))
+                self._reply(conn, encode_error, frame.sid,
+                            f"malformed frame payload: {e}")
                 self._drop_conn(conn)
-        self._flush(raw_batch, pieces_batch, closes)
+        self._flush(raw_batch, pieces_batch, closes, read_ns)
         if self._obs_on:
             self._h_route.observe(time.perf_counter_ns() - t_route)
             self._obs.tracer.add("transport.route", t_route,
@@ -601,20 +623,20 @@ class TransportServer:
         if frame.type == OPEN:
             mode, seed = decode_open(frame.payload)
             if sid in self._wire or sid in self.server:
-                self._reply(conn, encode_error(sid, "already open"))
+                self._reply(conn, encode_error, sid, "already open")
                 return
             before = set(self.server.evicted)
             try:
                 self.server.open(sid, key=jax.random.key(seed))
             except RuntimeError as e:  # table full, eviction disabled
-                self._reply(conn, encode_error(sid, str(e)))
+                self._reply(conn, encode_error, sid, str(e))
                 return
             self._wire[sid] = _WireSession(sid, mode, conn)
             self._notify_evicted(before)
         elif frame.type == DATA:
             w = self._wire.get(sid)
             if w is None:
-                self._reply(conn, encode_error(sid, "unknown session"))
+                self._reply(conn, encode_error, sid, "unknown session")
                 return
             if w.mode == MODE_RAW:
                 window = decode_data_raw(frame.payload)
@@ -639,7 +661,7 @@ class TransportServer:
         elif frame.type == CLOSE:
             w = self._wire.get(sid)
             if w is None:
-                self._reply(conn, encode_error(sid, "unknown session"))
+                self._reply(conn, encode_error, sid, "unknown session")
                 return
             t_seen, tail = decode_close(frame.payload)
             self.payload_bytes[w.mode] += len(frame.payload)
@@ -654,15 +676,16 @@ class TransportServer:
                 p["wire_bytes"] += 4.0  # the tail's f32 endpoint
             closes.append(sid)
         else:
-            self._reply(conn, encode_error(sid, "unexpected frame type"))
+            self._reply(conn, encode_error, sid, "unexpected frame type")
 
-    def _flush(self, raw_batch, pieces_batch, closes) -> None:  # symlint: hot-path
+    def _flush(self, raw_batch, pieces_batch, closes, read_ns) -> None:  # symlint: hot-path
         if raw_batch:
             arrivals = {sid: np.concatenate(ws) for sid, ws in
                         raw_batch.items() if sid in self.server}
             if arrivals:
-                deltas = self.server.ingest_many(arrivals)
-                self._route_deltas(deltas)
+                deltas = self.server.ingest_many(arrivals,
+                                                 record_latency=False)
+                self._route_deltas(deltas, read_ns)
         if pieces_batch:
             arrivals = {}
             for sid, p in pieces_batch.items():
@@ -679,8 +702,9 @@ class TransportServer:
                     "wire_bytes": p["wire_bytes"],
                 }
             if arrivals:
-                deltas = self.server.ingest_pieces_many(arrivals)
-                self._route_deltas(deltas)
+                deltas = self.server.ingest_pieces_many(arrivals,
+                                                        record_latency=False)
+                self._route_deltas(deltas, read_ns)
         for sid in closes:
             w = self._wire.pop(sid, None)
             if w is None or sid not in self.server:
@@ -688,20 +712,33 @@ class TransportServer:
             res = self.server.close(sid)
             self.closed_sessions += 1
             d = res["delta"]
-            self._reply(w.conn, encode_closed(
-                sid, res["n_pieces"], res["t_seen"], False,
-                d["labels"], d["endpoints"]))
+            self._reply(w.conn, encode_closed,
+                        sid, res["n_pieces"], res["t_seen"], False,
+                        d["labels"], d["endpoints"])
 
     def _seen(self, sid: str) -> int:
         return (self.server.session_stats(sid)["t_seen"]
                 if sid in self.server else 0)
 
-    def _route_deltas(self, deltas: Dict[str, dict]) -> None:
+    def _route_deltas(self, deltas: Dict[str, dict],
+                      read_ns: Dict[str, int]) -> None:
+        """Write each DELTA frame (one ``transport.reply`` span for the
+        batch); its symbols' latency runs from the read of the session's
+        first DATA frame of the batch to this write."""
+        obs_on = self._obs_on
+        t_rep = time.perf_counter_ns() if obs_on else 0
+        frames = 0
         for sid, d in deltas.items():
             w = self._wire.get(sid)
             if w is not None and d["frames"]:
-                self._reply(w.conn, encode_delta(
+                self._send(w.conn, encode_delta(
                     sid, d["labels"], d["endpoints"]))
+                frames += 1
+                if obs_on and d["n_new"] and sid in read_ns:
+                    self._h_symbol_lat.observe_n(
+                        time.perf_counter_ns() - read_ns[sid], d["n_new"])
+        if obs_on and frames:
+            self._obs.tracer.add("transport.reply", t_rep, {"frames": frames})
 
     def _notify_evicted(self, before) -> None:
         for sid in set(self.server.evicted) - before:
@@ -711,9 +748,9 @@ class TransportServer:
             self.closed_sessions += 1
             res = self.server.evicted[sid]
             d = res["delta"]
-            self._reply(w.conn, encode_closed(
-                sid, res["n_pieces"], res["t_seen"], True,
-                d["labels"], d["endpoints"]))
+            self._reply(w.conn, encode_closed,
+                        sid, res["n_pieces"], res["t_seen"], True,
+                        d["labels"], d["endpoints"])
 
     def summary(self) -> Dict[str, float]:
         """Actual-socket traffic next to the StreamServer's logical totals."""

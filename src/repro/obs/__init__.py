@@ -5,6 +5,8 @@ One ``Observability`` bundle per serving process: a ``MetricsRegistry``
 ``SpanTracer`` (bounded ring of Chrome trace events).  The stream,
 transport, and fleet layers all record into the same bundle, so one
 ``/metrics`` scrape or ``/trace`` download covers the whole pipeline.
+``current()`` hands that bundle to in-process tools (span arguments
+included), without a handle on the server that owns it.
 
 The recorder is hot-path safe by construction -- recording is host-side
 integer arithmetic, never a device sync -- and cheap enough to be on by
@@ -15,6 +17,7 @@ shared null instruments with zero recording cost.
 
 from __future__ import annotations
 
+import weakref
 from typing import Dict, Optional, Union
 
 from repro.obs.metrics import (
@@ -32,6 +35,7 @@ from repro.obs.tracing import SpanTracer, annotate
 __all__ = [
     "Observability",
     "as_obs",
+    "current",
     "MetricsRegistry",
     "SpanTracer",
     "Counter",
@@ -56,6 +60,9 @@ class Observability:
         # opt-in: also wrap device dispatch in jax profiler annotations so
         # spans land inside XLA device profiles (routed via jax_compat)
         self.jax_annotate = bool(jax_annotate) and self.enabled
+        if self.enabled:
+            global _CURRENT
+            _CURRENT = weakref.ref(self)
 
     def snapshot(self) -> Dict[str, object]:
         """JSON-able state for merging into server/fleet reports."""
@@ -66,6 +73,12 @@ class Observability:
 
 
 _DISABLED: Optional[Observability] = None
+_CURRENT: Optional["weakref.ref[Observability]"] = None
+
+
+def current() -> Optional[Observability]:
+    """The most recently built enabled bundle still alive, else ``None``."""
+    return _CURRENT() if _CURRENT is not None else None
 
 
 def disabled() -> Observability:

@@ -212,9 +212,9 @@ class TestDigitizeSpanTable:
 
         state, lengths, incs, hi = self._table(s, n_max, 8, 31 + s)
         lo = jnp.zeros((s,), jnp.int32)
-        st_t, sy_t = digitize_span_table(state, lengths, incs, lo, hi,
+        st_t, sy_t, _ = digitize_span_table(state, lengths, incs, lo, hi,
                                          **self.CFGK)
-        st_v, sy_v = jax.vmap(
+        st_v, sy_v, _ = jax.vmap(
             lambda st, le, ic, l, h: digitize_span(st, le, ic, l, h,
                                                    **self.CFGK)
         )(state, lengths, incs, lo, hi)
@@ -233,11 +233,11 @@ class TestDigitizeSpanTable:
             [int(rng.integers(0, int(h) + 1)) for h in np.asarray(hi)],
             jnp.int32)
         lo = jnp.zeros((s,), jnp.int32)
-        st_one, sy_one = digitize_span_table(state, lengths, incs, lo, hi,
+        st_one, sy_one, _ = digitize_span_table(state, lengths, incs, lo, hi,
                                              **self.CFGK)
-        st_a, sy_a = digitize_span_table(state, lengths, incs, lo, mid,
+        st_a, sy_a, _ = digitize_span_table(state, lengths, incs, lo, mid,
                                          **self.CFGK)
-        st_b, sy_b = digitize_span_table(st_a, lengths, incs, mid, hi,
+        st_b, sy_b, _ = digitize_span_table(st_a, lengths, incs, mid, hi,
                                          **self.CFGK)
         self._assert_state_equal(st_b, st_one, "split-resume")
         idx = np.arange(n_max)[None, :]

@@ -360,8 +360,6 @@ class TestServingIntegration:
             text, 'transport_frames_in_total{type="open"}') == len(sids)
         assert _prom_value(
             text, 'transport_frames_in_total{type="close"}') == len(sids)
-        assert _prom_value(
-            text, "transport_sessions_closed_total") == len(sids)
         assert _prom_value(text, 'transport_frames_in_total{type="data"}') > 0
         assert _prom_value(text, "transport_rx_bytes_total") > 0
         assert _prom_value(text, "transport_tx_bytes_total") > 0
