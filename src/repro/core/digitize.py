@@ -509,7 +509,10 @@ def digitizer_table_step(
     produces (control flow is hand-lowered exactly the way jax's batching
     rules do it: both cond branches computed + per-lane select, while-loop
     with an any() predicate and select-masked carries), keeping end-of-
-    stream results bitwise-equal to the per-slot path.
+    stream results bitwise-equal to the per-slot path.  The k-growth loop's
+    any() runs over the live clustering lanes alone (``live`` and past the
+    trivial phase): every other lane's clustering result is dropped by the
+    selects after it, so its rounds would change no bit.
 
     Args:
       state: DigitizerState with an (S,) leading axis on every leaf.
@@ -517,7 +520,8 @@ def digitizer_table_step(
       live:  (S,) bool -- lanes with ``live=False`` pass through unchanged.
 
     Returns ``(state, symbols (S,), rounds)`` -- symbol 0 for dead lanes;
-    ``rounds`` () is the number of k-growth rounds the table's loop ran.
+    ``rounds`` () is the number of k-growth rounds the table's loop ran,
+    the most any live clustering lane grew.
     """
     n_streams, n_max = state.pieces.shape[0], state.pieces.shape[1]
     k_cap = state.centers.shape[1]
@@ -546,7 +550,11 @@ def digitizer_table_step(
             lambda p, m: scale_coords(p, m, scl_arr))(pieces, mask)
         c_scaled = state.centers * scales[:, None, :]
         bound = jnp.asarray(tol, jnp.float32) ** 2
-        k_hi = jnp.minimum(jnp.asarray(k_max_active, jnp.int32), n)   # (S,)
+        # only a live lane past the trivial phase keeps this result: the
+        # others may not grow (k >= 1 > 0), so the any() below skips them
+        k_hi = jnp.where(
+            live & (n > k_min),
+            jnp.minimum(jnp.asarray(k_max_active, jnp.int32), n), 0)   # (S,)
         k_o = jnp.maximum(state.k, 1)
 
         def run(c_init, k):

@@ -401,7 +401,8 @@ class StreamServer:
             "table-step digitize trips (each step: its widest span)")
         self._m_rounds = m.counter(
             "symed_kgrowth_rounds_total",
-            "table-step k-growth rounds (each step: its slowest lane's)")
+            "table-step k-growth rounds run for kept lanes, summed over "
+            "each step's digitize trips")
         runs_help = ("table-step Lloyd k-means runs times lanes: executed "
                      "(every lane), useful (lanes digitizing or growing k)")
         self._m_lane_runs = m.counter("symed_lloyd_lane_runs_total",

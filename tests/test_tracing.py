@@ -5,7 +5,7 @@ loop's spans, and the chip benchmark's readers that use them.
   spans: trips are span lengths, the table and per-slot paths agree lane
   for lane, and ``lloyd_iters * (trips + rounds)`` is the number of Lloyd
   half-step calls the table step makes (a call-counting stub of the
-  kernel entry point).
+  kernel entry point), and only lanes whose result the step keeps grow k.
 * ``TestServedCounters`` -- the harvest span's arguments and the registry
   counters of a ``StreamServer``.
 * ``TestServeLoopSpans`` -- a loopback ``TransportServer`` records
@@ -51,6 +51,15 @@ def _table(s, n_max, seed):
     lengths = jnp.asarray(rng.integers(1, 9, size=(s, n_max)), jnp.float32)
     incs = jnp.asarray(rng.normal(0, 2, size=(s, n_max)), jnp.float32)
     return state, lengths, incs
+
+
+def _assert_state_equal(a, b, msg):
+    for name in a._fields:
+        la, lb = getattr(a, name), getattr(b, name)
+        if name == "key":
+            la, lb = jax.random.key_data(la), jax.random.key_data(lb)
+        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb),
+                                      err_msg=f"{msg}: {name}")
 
 
 class TestDigitizeWork:
@@ -138,6 +147,59 @@ class TestDigitizeWork:
             np.asarray(wa.growing_rounds) + np.asarray(wb.growing_rounds),
             np.asarray(w1.growing_rounds))
 
+    @pytest.mark.parametrize("seed", (11, 12))
+    @pytest.mark.parametrize("use_kernel", (False, True),
+                             ids=("reference", "kernel"))
+    def test_growth_runs_for_kept_lanes_only(self, use_kernel, seed):
+        """Lanes whose clustering result the step drops grow no round: a
+        lane past its span with ``n > k_min``, a lane in the trivial phase
+        and an empty slot leave the rounds and the live lanes' results as
+        a table of the live lanes alone has them."""
+        state, lengths, incs = _table(5, 16, seed)
+        zero = jnp.zeros((5,), jnp.int32)
+        ahead = jnp.asarray([0, 6, 0, 0, 0], jnp.int32)
+        state, _, _ = dz.digitize_span_table(state, lengths, incs, zero,
+                                             ahead, **SPAN_KW)
+        # lane 0 grows, 1 is past its span, 2 stays trivial, 3 is an empty
+        # slot, 4 runs out of its span while lane 0 goes on
+        lo = ahead
+        hi = jnp.asarray([10, 6, 2, 0, 5], jnp.int32)
+        run = functools.partial(dz.digitize_span_table, use_kernel=use_kernel,
+                                **SPAN_KW)
+        st, sy, work = run(state, lengths, incs, lo, hi)
+
+        # lane 1's phantom piece (at its clamped cursor) would grow k
+        lane1 = jax.tree.map(lambda leaf: leaf[1], state)
+        ghost, _ = dz.digitizer_step(lane1, jnp.stack(
+            [lengths[1, 6], incs[1, 6]]), **SPAN_KW)
+        assert int(ghost.k) > max(int(lane1.k), 1)
+
+        rounds = int(np.asarray(work.rounds_run)[0])
+        assert 0 < rounds <= int(np.asarray(work.growing_rounds).sum())
+
+        live = np.asarray([0, 2, 4])
+
+        def sub(tree):
+            return jax.tree.map(lambda leaf: leaf[live], tree)
+
+        st_l, sy_l, work_l = run(sub(state), lengths[live], incs[live],
+                                 lo[live], hi[live])
+        assert int(np.asarray(work_l.rounds_run)[0]) == rounds
+        _assert_state_equal(sub(st), st_l, "live lanes alone")
+        np.testing.assert_array_equal(np.asarray(sy)[live], np.asarray(sy_l))
+
+        st_v, sy_v, _ = jax.vmap(
+            lambda s, le, ic, a, b: dz.digitize_span(s, le, ic, a, b,
+                                                     **SPAN_KW)
+        )(state, lengths, incs, lo, hi)
+        if use_kernel:  # the kernel leaves 0 where the reference leaves
+            # whatever a label past n held: compare the pieces' labels
+            past = np.arange(16)[None, :] >= np.asarray(st_v.n)[:, None]
+            st, st_v = (x._replace(labels=jnp.where(past, 0, x.labels))
+                        for x in (st, st_v))
+        _assert_state_equal(st, st_v, "vmapped digitize_span")
+        np.testing.assert_array_equal(np.asarray(sy), np.asarray(sy_v))
+
 
 def _events(obs, prefix):
     return [(n, t0, d, a) for n, ph, t0, d, a in obs.tracer.events()
@@ -167,6 +229,8 @@ class TestServedCounters:
             assert a["lane_runs"] == srv.capacity * (a["trips"] + a["rounds"])
             assert 0 < a["useful_runs"] <= a["lane_runs"]
             assert a["slowest"] in sids or a["slowest_rounds"] == 0
+            # every growth round runs for a kept lane that grows in it
+            assert a["slowest_rounds"] > 0 or a["rounds"] == 0
         counters = obs.snapshot()["counters"]
         total = lambda k: sum(a[k] for *_, a in harvests)  # noqa: E731
         assert counters["symed_digitize_trips_total"] == total("trips")
